@@ -30,6 +30,7 @@ import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as Pspec
 from repro.core.distributed import kron_matmul_distributed
 from repro.runtime.hlo_cost import analyze
+from repro.runtime.sharding import make_mesh
 
 P, N = (int(sys.argv[1]), int(sys.argv[2])) if len(sys.argv) > 2 else (64, 4)
 quick = len(sys.argv) > 3 and sys.argv[3] == "quick"
@@ -38,7 +39,7 @@ for g in ([1, 4, 16] if quick else [1, 2, 4, 8, 16]):
     g_m = 1
     m = 4 * g          # weak scaling: rows grow with devices
     k = P ** N
-    mesh = jax.make_mesh((g_m, g), ("data", "model"),
+    mesh = make_mesh((g_m, g), ("data", "model"),
                          devices=jax.devices()[: g_m * g])
     # dry lowering: ShapeDtypeStructs only, no allocation (paper sizes are
     # GPU-memory-scale; comm volume comes from the compiled HLO)

@@ -28,6 +28,7 @@ import subprocess
 import sys
 
 from .util import bench_meta, csv_row
+from repro.runtime.sharding import make_mesh
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 OUT_JSON = ROOT / "BENCH_dist_batched.json"
@@ -60,7 +61,7 @@ def _child(quick: bool) -> None:
     b, m, ps, qs = 8, 32, (4, 4, 4), (4, 4, 4)
     iters = 12 if quick else 24
     g_m, g_k = MESH_SHAPE
-    mesh = jax.make_mesh(MESH_SHAPE, ("data", "model"))
+    mesh = make_mesh(MESH_SHAPE, ("data", "model"))
 
     def bench_pair(fn_a, fn_b, rounds_=6):
         """Block-interleaved min-of-N (same estimator as fig_batched)."""

@@ -32,6 +32,7 @@ import subprocess
 import sys
 
 from .util import bench_meta, csv_row
+from repro.runtime.sharding import make_mesh
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 OUT_JSON = ROOT / "BENCH_dist_overlap.json"
@@ -72,7 +73,7 @@ def _child(quick: bool) -> None:
     b_tuner = 8
     iters = 12 if quick else 24
     g_m, g_k = MESH_SHAPE
-    mesh = jax.make_mesh(MESH_SHAPE, ("data", "model"))
+    mesh = make_mesh(MESH_SHAPE, ("data", "model"))
 
     rev_ps, rev_qs = list(reversed(ps)), list(reversed(qs))
     k_loc = math.prod(ps) // g_k

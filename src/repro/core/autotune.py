@@ -46,28 +46,25 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import emit as emit_mod
+from ..kernels import hardware
 from ..kernels.emit import StageInstr, StageProgram, fused_growth
 from ..runtime import chaos, guard, telemetry
 from .kron import KronProblem
 
-# TPU v5e hardware model (same constants as EXPERIMENTS.md).
-PEAK_FLOPS = 197e12  # bf16
-PEAK_FLOPS_F32 = 98.5e12
-HBM_BW = 819e9  # bytes/s
-VMEM_BYTES = 16 * 1024 * 1024
+# Hardware peaks come from ``kernels.hardware``, keyed by device_kind: the
+# attached TPU, or the default target chip when planning off-device.
 MXU_DIM = 128
 SUBLANE = 8
 
-# Interconnect model for the distributed slab pipeline (TPU v5e ICI): the
-# per-device all_to_all streams at ICI_BW and each collective launch pays
+# Interconnect model for the distributed slab pipeline: the per-device
+# all_to_all streams at the chip's ``ici_bw`` and each collective launch pays
 # A2A_LATENCY_S regardless of payload.  Slabbing a round multiplies the
 # latency term by n_slabs while letting up to (n-1)/n of the payload hide
 # under chain compute — so the analytic model only picks n_slabs > 1 once
-# per-round payloads clear the ~latency*BW product (~100 KB), which keeps
+# per-round payloads clear the ~latency*BW product (~45 KB on v5e), which keeps
 # every small test problem on the serial schedule.  Host-mesh collectives
 # run at memcpy speed, so ``tune="measure"`` (not this model) owns the final
 # call on real fabrics — see ``make_batched_plan``.
-ICI_BW = 45e9  # bytes/s per device
 A2A_LATENCY_S = 1e-6
 
 PLAN_CACHE_VERSION = 1
@@ -77,8 +74,7 @@ def _ceil_to(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _divisors(n: int) -> list[int]:
-    return [d for d in range(1, n + 1) if n % d == 0]
+_divisors = emit_mod._divisors
 
 
 @dataclasses.dataclass(frozen=True)
@@ -110,26 +106,45 @@ def predict_seconds(
     u_q = cfg.t_q / _ceil_to(cfg.t_q, MXU_DIM)
     rows = cfg.t_m * cfg.t_s
     u_r = rows / _ceil_to(rows, SUBLANE)
-    peak = PEAK_FLOPS if dtype_bytes <= 2 else PEAK_FLOPS_F32
+    hw = hardware.tpu_spec()
+    peak = hw.peak_flops_bf16 if dtype_bytes <= 2 else hw.peak_flops_f32
     t_compute = flops / (peak * max(u_c * u_q * u_r, 1e-6))
     # HBM traffic: X re-read once per Q-tile sweep; F negligible; Y written once.
     x_bytes = prob_m * s * p * dtype_bytes * (q // cfg.t_q)
     y_bytes = prob_m * s * q * dtype_bytes
     f_bytes = p * q * dtype_bytes * (prob_m // cfg.t_m) * (s // cfg.t_s)
-    t_mem = (x_bytes + y_bytes + f_bytes) / HBM_BW
+    t_mem = (x_bytes + y_bytes + f_bytes) / hw.hbm_bw
     return max(t_compute, t_mem)
 
 
-def candidate_tiles(m: int, s: int, p: int, q: int) -> list[TileConfig]:
-    """Paper §4.3 search-space narrowing, restated for Pallas blocks."""
-    t_ms = [t for t in (1, 2, 4, 8, 16, 32) if t <= m and m % t == 0]
-    t_ss = [t for t in _divisors(s) if t <= 2048]
-    # keep lane-friendly slice tiles preferentially but allow all divisors
-    t_qs = _divisors(q)
+def _legal_rows(m: int, dtype_bytes: int = 4) -> list[int]:
+    """Row tiles a TPU block may have: M, or multiples of the sublane tile."""
+    sub = emit_mod._sublane(dtype_bytes)
+    return [d for d in _divisors(m) if d == m or d % sub == 0]
+
+
+def _legal_slices(s: int) -> list[int]:
+    """Slice tiles a TPU block may have: S, or multiples of 128 lanes."""
+    return [d for d in _divisors(s) if d == s or d % MXU_DIM == 0]
+
+
+def candidate_tiles(
+    m: int, s: int, p: int, q: int, dtype_bytes: int = 4
+) -> list[TileConfig]:
+    """Paper §4.3 search-space narrowing, restated for Pallas blocks.
+
+    Only legal TPU blocks are proposed: a block's last two dims are tile
+    multiples or the full extent, so rows are M or multiples of the 8-row
+    sublane tile, slice counts S or multiples of 128 lanes, and Q-tiles Q
+    or multiples of 8."""
+    t_ms = [t for t in _legal_rows(m, dtype_bytes) if t <= 32] or [m]
+    t_ss = [t for t in _legal_slices(s) if t == s or t <= 2048]
+    t_qs = [t for t in _divisors(q) if t == q or t % SUBLANE == 0]
+    vmem_cap = hardware.tpu_spec().scoped_vmem_bytes * 3 // 4
     out = []
     for t_m, t_s, t_q in itertools.product(t_ms, t_ss, t_qs):
         cfg = TileConfig(t_m, t_s, t_q)
-        if vmem_elems(cfg, p) * 4 > VMEM_BYTES * 3 // 4:
+        if vmem_elems(cfg, p) * 4 > vmem_cap:
             continue  # resource-limit pruning (paper: smem + regs cap)
         out.append(cfg)
     return out
@@ -139,9 +154,9 @@ def tune_sliced(
     m: int, s: int, p: int, q: int, *, dtype_bytes: int = 4
 ) -> TileConfig:
     """Best analytic tile config for a single sliced multiply."""
-    cands = candidate_tiles(m, s, p, q)
+    cands = candidate_tiles(m, s, p, q, dtype_bytes)
     if not cands:
-        return TileConfig(min(m, 8), 1, 1)
+        return TileConfig(min(_legal_rows(m, dtype_bytes)), min(_legal_slices(s)), q)
     return min(cands, key=lambda c: predict_seconds(m, s, p, q, c, dtype_bytes))
 
 
@@ -155,7 +170,11 @@ def measure_best(
     """Wall-clock ranking of candidates (for real hardware).
 
     Generic over the candidate type: tile configs for one kernel, or whole
-    ``KronPlan``s in ``make_plan(tune="measure")``.
+    ``KronPlan``s in ``make_plan(tune="measure")``.  A candidate that fails
+    to run with one of the library's typed errors or a runtime (compile /
+    execution) error is dropped and recorded as a ``measure_dropped`` event
+    in ``guard.health_report()``; any other exception is a bug and
+    propagates.
     """
     best, best_t = None, float("inf")
     for cfg in cands:
@@ -167,7 +186,8 @@ def measure_best(
             for _ in range(iters):
                 jax.block_until_ready(fn())
             dt = (time.perf_counter() - t0) / iters
-        except Exception:
+        except (guard.KronError, RuntimeError, ValueError) as e:
+            guard.record_event("measure_dropped", e)
             continue
         if dt < best_t:
             best, best_t = cfg, dt
@@ -332,6 +352,19 @@ def lower(
     return StageProgram(tuple(instrs), len(rps))
 
 
+def _chip_fits(m: int, k: int, ps, qs, dtype_bytes: int) -> bool:
+    """Whether a stage chaining ``ps``/``qs`` over (m, k) has a legal compiled
+    tiling for both its forward and its stage-backward kernel."""
+    return all(
+        emit_mod.legal_tiles(
+            "fwd", 1, m, k, ps, qs, t_b=1, t_m=8, itemsize=dtype_bytes,
+            grad=grad,
+        )
+        is not None
+        for grad in (False, True)
+    )
+
+
 def make_plan(
     prob: KronProblem,
     *,
@@ -384,6 +417,7 @@ def make_plan(
     stages: list[Stage] = []
     k = prob.k
     i = 0
+    on_chip = emit_mod.resolve_backend(backend) == "pallas"
     while i < n:
         p, q = ps[i], qs[i]
         # -- beyond-paper pre-kronization --
@@ -422,6 +456,11 @@ def make_plan(
                         break
                 if tq_j is None:
                     break
+                if on_chip and not _chip_fits(
+                    prob.m, k, [ps[g] for g in group] + [ps[j]],
+                    [qs[g] for g in group] + [qs[j]], dtype_bytes,
+                ):
+                    break  # no legal kernel tiling holds the longer chain
                 pprod, tqprod = np_, tqprod * tq_j
                 group.append(j)
                 group_tqs.append(tq_j)
@@ -458,12 +497,14 @@ def make_plan(
             # budget (the grouping loop guaranteed a fit at T_M=8, t_s=1).
             growth = fused_growth([ps[g] for g in group], [qs[g] for g in group], t_qs)
             t_m = tiles.t_m
-            while t_m > 1 and t_m * pprod * growth > vmem_budget_elems:
-                t_m = max(d for d in _divisors(prob.m) if d < t_m)
+            rows = _legal_rows(prob.m, dtype_bytes)
+            while t_m > rows[0] and t_m * pprod * growth > vmem_budget_elems:
+                t_m = max(d for d in rows if d < t_m)
             max_ts = max(1, int(vmem_budget_elems // (t_m * pprod * growth)))
             ts = tiles.t_s
             if ts > max_ts:
-                ts = max(d for d in _divisors(s) if d <= max_ts)
+                slices = _legal_slices(s)
+                ts = max((d for d in slices if d <= max_ts), default=slices[0])
             if (t_m, ts) != (tiles.t_m, tiles.t_s):
                 tiles = TileConfig(t_m, ts, tiles.t_q)
         stages.append(Stage(tuple(group), False, tiles, t_qs, acc_dtype))
@@ -512,7 +553,7 @@ def _dist_round_costs(
 ) -> list[tuple[float, float]]:
     """Per-round ``(compute_s, comm_s)`` on one device of the mesh round
     schedule: chain flops against the dtype's peak, all_to_all payload
-    against ``ICI_BW``.  ``prob`` is the LOCAL problem (``m = M_loc``).
+    against the chip's ``ici_bw``.  ``prob`` is the LOCAL problem (``m = M_loc``).
     Raises ``PlanError`` when no round schedule exists (callers fall back to
     the serial schedule)."""
     from .distributed import plan_rounds
@@ -521,7 +562,8 @@ def _dist_round_costs(
     qs = list(reversed(prob.qs))
     k_loc = prob.k // g_k
     rounds = plan_rounds(k_loc, ps, qs, g_k)
-    peak = PEAK_FLOPS if dtype_bytes <= 2 else PEAK_FLOPS_F32
+    hw = hardware.tpu_spec()
+    peak = hw.peak_flops_bf16 if dtype_bytes <= 2 else hw.peak_flops_f32
     costs = []
     c = k_loc
     i = 0
@@ -531,7 +573,7 @@ def _dist_round_costs(
             flops += 2.0 * batch * prob.m * c * qs[j]
             c = c // ps[j] * qs[j]
         payload = batch * prob.m * c * (g_k - 1) / g_k
-        costs.append((flops / peak, payload * dtype_bytes / ICI_BW))
+        costs.append((flops / peak, payload * dtype_bytes / hw.ici_bw))
         i += r
     return costs
 
@@ -565,7 +607,7 @@ def choose_n_slabs(
     candidate is clamped to a divisor of the row axis, scored with
     ``_slab_schedule_seconds``, and the serial schedule wins ties — the
     latency term means slabbing only pays once per-round payloads clear
-    roughly ``A2A_LATENCY_S * ICI_BW`` (~100 KB per collective), so small
+    roughly ``A2A_LATENCY_S * ici_bw`` (~45 KB per collective), so small
     problems always plan serial.  This is the HBM-class analytic model;
     ``make_batched_plan(tune="measure", mesh=...)`` overrules it with a wall
     clock on the emitted program."""
@@ -625,13 +667,14 @@ def _batch_tiled(
         return max(d for d in _divisors(batch) if d <= cap)
 
     t_b = best_t_b()
+    rows = _legal_rows(prob.m, dtype_bytes)
     while t_b < min(batch, SUBLANE):
-        reducible = [i for i, st in enumerate(stages) if st.tiles.t_m > 1]
+        reducible = [i for i, st in enumerate(stages) if st.tiles.t_m > rows[0]]
         if not reducible:
             break
         i = max(reducible, key=lambda i: stages[i].tiles.t_m)
         st = stages[i]
-        new_tm = max(d for d in _divisors(prob.m) if d < st.tiles.t_m)
+        new_tm = max(d for d in rows if d < st.tiles.t_m)
         stages[i] = dataclasses.replace(
             st, tiles=TileConfig(new_tm, st.tiles.t_s, st.tiles.t_q)
         )
@@ -1298,9 +1341,5 @@ __all__ = [
     "load_plan_cache",
     "save_plan_cache",
     "default_cache_path",
-    "PEAK_FLOPS",
-    "HBM_BW",
-    "VMEM_BYTES",
-    "ICI_BW",
     "A2A_LATENCY_S",
 ]

@@ -61,17 +61,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """Version shim: jax.shard_map(check_vma=...) landed after 0.4.x; fall
-    back to jax.experimental.shard_map.shard_map(check_rep=...)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
-
 from ..kernels import emit
 from ..runtime import chaos, guard, telemetry
 
@@ -611,11 +600,12 @@ def run_distributed_rounds(
         g_k, model_axis, backend, per_iteration, None, int(n_slabs)
     )
     spec_x = P(data_axis, model_axis)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda x_loc, fs: body(x_loc, tuple(reversed(fs))),
         mesh=mesh,
         in_specs=(spec_x, P()),
         out_specs=spec_x,
+        check_vma=False,
     )
     return fn(x, factors)
 
@@ -667,11 +657,12 @@ def run_batched_distributed_rounds(
         int(t_b), int(n_slabs),
     )
     spec_x = P(None, data_axis, model_axis)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda x_loc, fs: body(x_loc, tuple(reversed(fs))),
         mesh=mesh,
         in_specs=(spec_x, P()),
         out_specs=spec_x,
+        check_vma=False,
     )
     return fn(x, factors)
 

@@ -61,7 +61,7 @@ import jax.numpy as jnp
 from jax.extend.core import Primitive
 from jax.interpreters import batching, mlir
 
-from ..kernels import emit, ops
+from ..kernels import emit, hardware, ops
 from ..runtime import chaos, guard, telemetry
 from . import autotune
 from .autotune import KronPlan, Stage, TileConfig
@@ -641,7 +641,7 @@ class KronCost:
     critical path.  ``comm_hidden_elems`` is the analytic upper bound on the
     hidden share (``distributed.comm_hidden_elems``) and
     ``critical_path_s`` the resulting per-call wall-clock estimate —
-    compute at the dtype's peak plus the EXPOSED transfer at ``ICI_BW``
+    compute at the dtype's peak plus the EXPOSED transfer at the chip's ``ici_bw``
     plus one launch latency per collective.  Defaults keep local ops (and
     serial mesh schedules) at the historical ``KronCost(flops, comm,
     rounds)`` shape: nothing hidden, one collective per round.
@@ -1061,14 +1061,15 @@ class KronOp:
             rounds=self.rounds, batch=comm_batch, n_slabs=n,
         )
         # Critical path: per-device compute at the dtype's peak, the EXPOSED
-        # transfer at ICI_BW, one launch latency per collective issued.
+        # transfer at the chip's ici_bw, one launch latency per collective issued.
+        hw = hardware.tpu_spec()
         peak = (
-            autotune.PEAK_FLOPS if self._dtype_bytes <= 2
-            else autotune.PEAK_FLOPS_F32
+            hw.peak_flops_bf16 if self._dtype_bytes <= 2
+            else hw.peak_flops_f32
         )
         critical = (
             flops / (self.g_m * self.g_k) / peak
-            + (comm - hidden) * self._dtype_bytes / autotune.ICI_BW
+            + (comm - hidden) * self._dtype_bytes / hw.ici_bw
             + len(self.rounds) * n * autotune.A2A_LATENCY_S
         )
         return KronCost(flops, comm, len(self.rounds), hidden, n, critical)
@@ -1119,6 +1120,7 @@ class KronOp:
         )
         if self.mesh is not None:
             cost = self.cost(report["signature"]["m"])
+            hw = hardware.tpu_spec()
             report["signature"]["mesh"] = [self.g_m, self.g_k]
             report["comm"] = {
                 "elems_per_device": cost.comm_elems_per_device,
@@ -1128,7 +1130,7 @@ class KronOp:
                 "critical_path_s": cost.critical_path_s,
                 "predicted_s": cost.comm_elems_per_device
                 * self._dtype_bytes
-                / autotune.HBM_BW,
+                / hw.hbm_bw,
                 "measured_s": None,  # rounds run inside shard_map bodies
             }
             # Reconcile the analytic overlap term against the per-slab
@@ -1177,8 +1179,9 @@ class KronOp:
             )
         prog = _lowered(plan, self.ps, self.qs, batched)
         rev = tuple(reversed(factors))
+        hw = hardware.tpu_spec()
         peak = (
-            autotune.PEAK_FLOPS if dtype_bytes <= 2 else autotune.PEAK_FLOPS_F32
+            hw.peak_flops_bf16 if dtype_bytes <= 2 else hw.peak_flops_f32
         )
         stages: list[dict] = []
         measured: list[float] = []
@@ -1201,7 +1204,7 @@ class KronOp:
                     jax.block_until_ready(out)
                     best = min(best, time.perf_counter() - t0)
                 flops, nbytes = _stage_flops_bytes(y.shape, instr, dtype_bytes)
-                pred = flops / peak + nbytes / autotune.HBM_BW
+                pred = flops / peak + nbytes / hw.hbm_bw
                 measured.append(best)
                 predicted.append(pred)
                 stages.append(
@@ -1253,6 +1256,45 @@ class KronOp:
             "iters": iters,
         }
 
+    def stage_executors(
+        self, m: int | None = None, dtype=jnp.float32
+    ) -> list[tuple[str, str, str]]:
+        """How each planned stage runs on the COMPILED Pallas backend for
+        ``m`` rows (default: the op's row hint, else 16): ``pallas(t_b, t_m,
+        t_k)`` with the legal tiles the emitter uses, or ``xla`` where no
+        legal kernel tiling fits VMEM — for the forward kernel and for the
+        stage-backward kernel.  Decided from shapes alone, never from a
+        failed compile.  One ``(stage, forward, backward)`` triple per
+        stage; empty for mesh ops without a stage plan."""
+        m = self._default_rows() if m is None else int(m)
+        itemsize = jnp.dtype(dtype).itemsize
+        per_sample = self.batch is not None and not self.shared_factors
+        if self.mesh is not None and not per_sample:
+            return []
+        if per_sample:
+            plan = self._batched_plan(self.batch, m, itemsize)
+            lead = (self.batch,)
+        else:
+            m = m if self.batch is None else self.batch * m
+            plan = self._single_plan(m, itemsize)
+            lead = ()
+        if plan is None:
+            return []
+
+        def how(tiles):
+            return "xla" if tiles is None else f"pallas{tiles}"
+
+        cols, out = self.k, []
+        for ins in _lowered(plan, self.ps, self.qs, per_sample).instrs:
+            shape = lead + (m, cols)
+            out.append((
+                ins.describe(),
+                how(emit.stage_tiles(ins, shape, dtype)),
+                how(emit.stage_tiles(ins, shape, dtype, grad=True)),
+            ))
+            cols = cols // ins.pprod * ins.qprod
+        return out
+
     def describe(self) -> str:
         mode = "batched" if self.batch is not None else "single"
         shared = "" if self.batch is None else (
@@ -1272,6 +1314,13 @@ class KronOp:
             f"KronOp(ps={list(self.ps)}, qs={list(self.qs)}, {mode}"
             f"{shared}, {where}, backend={self.backend}) :: {pdesc}"
         )
+        if emit.resolve_backend(self.backend) == "pallas" and plan is not None:
+            # Which stages the compiled emitter routes to XLA (no legal
+            # kernel tiling at the op's row hint), decided from shapes.
+            execs = self.stage_executors()
+            base += " :: exec[" + "; ".join(
+                f"{fwd}/{grad}" for _, fwd, grad in execs
+            ) + "]"
         return base + self._health_suffix() + self._telemetry_suffix()
 
     def _telemetry_suffix(self) -> str:

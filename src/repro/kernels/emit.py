@@ -41,9 +41,11 @@ from jax.experimental import pallas as pl
 from repro.runtime import chaos, guard, telemetry
 from repro.runtime.guard import LoweringError, VmemOverflowError
 
-# Conservative usable-VMEM budget (f32 elements): ~16 MiB VMEM, keep half for
-# double buffering / Mosaic temporaries.
-VMEM_BUDGET_ELEMS = 2 * 1024 * 1024
+# Usable-VMEM budget of one kernel, in f32 elements (64 MiB): half of the
+# v5e's 128 MiB, counted after tile padding by ``chain_vmem_bytes``.  The
+# compiler's own limit is set to three quarters of the chip's VMEM
+# (``_compiler_params``), which leaves room for what the model leaves out.
+VMEM_BUDGET_ELEMS = 16 * 1024 * 1024
 
 # CPU cache budget for the scan-fused XLA executor (the L2/L3 analogue of the
 # Pallas kernels' VMEM budget): chains whose whole working set fits are run
@@ -224,6 +226,16 @@ def transpose(prog: StageProgram) -> StageProgram:
 # Batch-polymorphic primitive bodies (the deduped `_sliced_body*` family)
 # ---------------------------------------------------------------------------
 
+# Every GEMM of both executors runs at full input precision: on a TPU the
+# default for f32 operands is one bf16 pass, ~1e-3 relative error.
+_PREC = jax.lax.Precision.HIGHEST
+
+
+def _dot(a, b, dims, acc):
+    return jax.lax.dot_general(
+        a, b, dims, preferred_element_type=acc, precision=_PREC
+    )
+
 
 def sliced_apply(y: jax.Array, f: jax.Array, acc_dtype=None) -> jax.Array:
     """One FastKron sliced multiply, batch-polymorphic.
@@ -240,10 +252,7 @@ def sliced_apply(y: jax.Array, f: jax.Array, acc_dtype=None) -> jax.Array:
         m, k = y.shape
         p, q = f.shape
         s = k // p
-        out = jax.lax.dot_general(
-            y.reshape(m * s, p), f, (((1,), (0,)), ((), ())),
-            preferred_element_type=acc,
-        )
+        out = _dot(y.reshape(m * s, p), f, (((1,), (0,)), ((), ())), acc)
         return (
             jnp.swapaxes(out.reshape(m, s, q), 1, 2).reshape(m, q * s)
             .astype(y.dtype)
@@ -251,10 +260,7 @@ def sliced_apply(y: jax.Array, f: jax.Array, acc_dtype=None) -> jax.Array:
     b, m, k = y.shape
     p, q = int(f.shape[1]), int(f.shape[2])
     s = k // p
-    out = jax.lax.dot_general(
-        y.reshape(b, m * s, p), f, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=acc,
-    )
+    out = _dot(y.reshape(b, m * s, p), f, (((2,), (1,)), ((0,), (0,))), acc)
     return (
         jnp.swapaxes(out.reshape(b, m, s, q), 2, 3).reshape(b, m, q * s)
         .astype(y.dtype)
@@ -275,20 +281,18 @@ def sliced_apply_t(g: jax.Array, f: jax.Array, acc_dtype=None) -> jax.Array:
         m, l = g.shape
         p, q = f.shape
         s = l // q
-        out = jax.lax.dot_general(
+        out = _dot(
             jnp.swapaxes(g.reshape(m, q, s), 1, 2).reshape(m * s, q),
             jnp.swapaxes(f, 0, 1),
             (((1,), (0,)), ((), ())),
-            preferred_element_type=acc,
+            acc,
         )
         return out.reshape(m, s * p).astype(g.dtype)
     b, m, l = g.shape
     p, q = int(f.shape[1]), int(f.shape[2])
     s = l // q
     g2 = jnp.swapaxes(g.reshape(b, m, q, s), 2, 3).reshape(b, m * s, q)
-    out = jax.lax.dot_general(
-        g2, f, (((2,), (2,)), ((0,), (0,))), preferred_element_type=acc
-    )
+    out = _dot(g2, f, (((2,), (2,)), ((0,), (0,))), acc)
     return out.reshape(b, m, s * p).astype(g.dtype)
 
 
@@ -393,70 +397,187 @@ def max_n_fused(t_k: int, p: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# THE Pallas kernel template (chain, both directions, batch grid axis)
+# THE Pallas kernel templates (chain, both directions, batch grid axis)
 # ---------------------------------------------------------------------------
+#
+# Mosaic cannot split the lane axis into pieces narrower than 128, so no
+# kernel reshapes a row of x into (slices, P).  Each tile is transposed ONCE
+# on entry into a working matrix W whose ROWS carry the stage's Kronecker
+# digits and whose LANES carry the tile's rows of x — plus, when the tile's
+# slice count ``ts`` is a multiple of 128 ("dense" lanes), its slices:
+#
+#   not dense:  W = (ts * R, t_m)   rows (slice, digits...), lanes m
+#   dense:      W = (R, t_m * ts)   rows (digits...),        lanes (m, slice)
+#
+# A factor step contracts the MINOR row digit p and writes the new digit q as
+# the MAJOR row digit — FastKron's layout rotation, here a leading/sublane
+# swap plus one GEMM: (s*p, L) -> (p, s*L) -> F^T @ -> (q*s, L).  When L is
+# not a multiple of 128 the lanes cannot absorb s, and the step runs as a
+# batched GEMM over s instead.  The transposed step is the exact inverse.
+
+LANE = 128
+KERNEL_NAMES = ("kron_chain_fwd", "kron_chain_bwd", "kron_stage_grad")
+
+
+def _pad_to(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _sublane(itemsize: int) -> int:
+    """Rows of one native VMEM tile: 8 for 32-bit dtypes, 16 for 16-bit."""
+    return 8 * max(1, 4 // int(itemsize))
+
+
+def _tiled_bytes(shape, itemsize: int = 4) -> int:
+    """VMEM bytes of a buffer once its last two dims are padded to tiles."""
+    shape = (1, 1) + tuple(int(d) for d in shape)
+    lead = math.prod(shape[:-2])
+    return (
+        lead * _pad_to(shape[-2], _sublane(itemsize)) * _pad_to(shape[-1], LANE)
+        * int(itemsize)
+    )
+
+
+def _divisors(n: int) -> list[int]:
+    small = [d for d in range(1, math.isqrt(n) + 1) if n % d == 0]
+    return sorted(set(small + [n // d for d in small]))
+
+
+def _x_to_w(v, ts: int, dense: bool):
+    """x-layout tile (t_m, ts*R) (slice major) -> working matrix."""
+    t_m, t_k = v.shape
+    if not dense:
+        return v.T
+    r = t_k // ts
+    if r % LANE == 0:  # lane-aligned digits: split lanes, skip the big transpose
+        w = jnp.swapaxes(v.reshape(t_m, ts, r), 1, 2)  # (t_m, r, ts)
+        return jnp.swapaxes(w, 0, 1).reshape(r, t_m * ts)
+    w = jnp.swapaxes(v.T.reshape(ts, r, t_m), 0, 1)  # (r, ts, t_m)
+    return jnp.swapaxes(w, 1, 2).reshape(r, t_m * ts)
+
+
+def _w_to_x(w, t_m: int, ts: int, dense: bool):
+    """Inverse of ``_x_to_w``."""
+    if not dense:
+        return w.T
+    r = w.shape[0]
+    if r % LANE == 0:
+        v = jnp.swapaxes(w.reshape(r, t_m, ts), 0, 1)  # (t_m, r, ts)
+        return jnp.swapaxes(v, 1, 2).reshape(t_m, ts * r)
+    v = jnp.swapaxes(w.reshape(r, t_m, ts), 1, 2)  # (r, ts, t_m)
+    return jnp.swapaxes(v, 0, 1).reshape(ts * r, t_m).T
+
+
+def _y_to_w(v, ts: int, dense: bool):
+    """y-layout tile (t_m, R*ts) or (t_m, R..., ts) (slice minor) -> W."""
+    t_m = v.shape[0]
+    if not dense:
+        return v.T
+    r = math.prod(v.shape[1:]) // ts
+    return jnp.swapaxes(v.reshape(t_m, r, ts), 0, 1).reshape(r, t_m * ts)
+
+
+def _w_to_y(w, t_m: int, ts: int, dense: bool):
+    """W -> y layout: (t_m, R, ts) when dense, else the flat (t_m, R*ts)."""
+    if not dense:
+        return w.T
+    return jnp.swapaxes(w.reshape(w.shape[0], t_m, ts), 0, 1)
+
+
+def _step(w, f, acc, merge: bool):
+    """Contract the minor row digit p of W (s*p, L) with f (p, q): (q*s, L).
+
+    ``merge``: fold s into the lanes for one 2-D GEMM — legal in Mosaic only
+    for lane-aligned L (``_merges``); otherwise a GEMM batched over s."""
+    r, lanes = w.shape
+    p, q = f.shape
+    s = r // p
+    if merge:
+        z = jnp.swapaxes(w.reshape(s, p, lanes), 0, 1).reshape(p, s * lanes)
+        return _dot(f, z, (((0,), (0,)), ((), ())), acc).reshape(q * s, lanes)
+    ft = jnp.broadcast_to(f.T, (s, q, p))
+    o = _dot(ft, w.reshape(s, p, lanes), (((2,), (1,)), ((0,), (0,))), acc)
+    return jnp.swapaxes(o, 0, 1).reshape(q * s, lanes)
+
+
+def _step_t(g, f, acc, merge: bool, u=None):
+    """Transposed step: contract the major row digit q of G (q*s, L) with
+    f (p, q), giving (s*p, L).  With ``u`` (the step's forward input, rows
+    (s, p)) also returns the factor gradient sum_{s,L} u[s,p] g[q,s], sharing
+    G's relayout between the two GEMMs."""
+    r, lanes = g.shape
+    p, q = f.shape
+    s = r // q
+    df = None
+    if merge:
+        g2 = g.reshape(q, s * lanes)
+        if u is not None:
+            u2 = jnp.swapaxes(u.reshape(s, p, lanes), 0, 1).reshape(p, s * lanes)
+            df = _dot(u2, g2, (((1,), (1,)), ((), ())), acc)
+        o = _dot(f, g2, (((1,), (0,)), ((), ())), acc)
+        out = jnp.swapaxes(o.reshape(p, s, lanes), 0, 1).reshape(s * p, lanes)
+    else:
+        g3 = jnp.swapaxes(g.reshape(q, s, lanes), 0, 1)  # (s, q, L)
+        if u is not None:
+            df = _dot(
+                u.reshape(s, p, lanes), g3, (((2,), (2,)), ((0,), (0,))), acc
+            ).sum(axis=0)
+        fb = jnp.broadcast_to(f, (s, p, q))
+        out = _dot(fb, g3, (((2,), (1,)), ((0,), (0,))), acc).reshape(s * p, lanes)
+    return out if u is None else (df, out)
+
+
+def _merges(lanes: int, interpret: bool) -> bool:
+    """Whether a factor step folds its slices into the lanes (one 2-D GEMM).
+    Interpreted, always: there is no lane constraint, and the 2-D GEMM sums
+    in the same order as the XLA executor."""
+    return interpret or lanes % LANE == 0
 
 
 def _chain_kernel(
-    x_ref, *refs, ps: tuple[int, ...], qs: tuple[int, ...], direction: str,
-    acc_dtype,
+    x_ref, *refs, n: int, ts: int, dense: bool, direction: str, acc_dtype,
+    interpret: bool,
 ):
     """One parameterized kernel body for every fused chain.
 
-    Tiles always carry a leading batch axis (size 1 when the instruction is
-    unbatched); every GEMM is a ``dot_general`` with a batch dimension, so
-    sample b's tile only ever contracts against sample b's factor slice.
-    ``direction="fwd"`` chains the factors (Algorithm 1 order, f_refs[0]
-    first); ``"bwd"`` inverts the chain with transposed contractions and
-    accumulates partial dX tiles across the sequential Q-tile grid axis.
+    Blocks carry a leading batch axis (size 1 when the instruction is
+    unbatched); samples of a block are walked one at a time, each against its
+    own factor slice.  ``direction="fwd"`` chains the factors (f_refs[0]
+    first); ``"bwd"`` inverts the chain and accumulates partial dX tiles over
+    the sequential Q-tile grid axis.
     """
-    f_refs, (y_ref,) = refs[:-1], refs[-1:]
-    t_b, t_m = x_ref.shape[0], x_ref.shape[1]
-    if direction == "fwd":
-        y = x_ref[...]
-        cols = x_ref.shape[2]
-        for f_ref, p, q in zip(f_refs, ps, qs):
-            s = cols // p
-            acc = jax.lax.dot_general(
-                y.reshape(t_b, t_m * s, p), f_ref[...],
-                (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=acc_dtype,
-            )  # (t_b, t_m*s, q)
-            # FastKron layout (b, m, q, s) — stays in VMEM between factors.
-            y = jnp.swapaxes(acc.reshape(t_b, t_m, s, q), 2, 3).reshape(
-                t_b, t_m, q * s
-            )
-            cols = q * s
-        y_ref[...] = y.reshape(y_ref.shape).astype(y_ref.dtype)
-        return
-    # Transposed chain: the forward applied f_refs[0] first, so its transpose
-    # is applied last; the most-recently-applied factor's q is the major
-    # digit of the current layout and is contracted first.
-    jq = pl.program_id(3)
-    g = x_ref[...].reshape(t_b, t_m, -1).astype(acc_dtype)
-    cols = g.shape[2]
-    for f_ref, p, q in reversed(list(zip(f_refs, ps, qs))):
-        s = cols // q
-        g2 = jnp.swapaxes(g.reshape(t_b, t_m, q, s), 2, 3).reshape(
-            t_b, t_m * s, q
-        )
-        acc = jax.lax.dot_general(
-            g2, f_ref[...], (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=acc_dtype,
-        )  # (t_b, t_m*s, p)
-        g = acc.reshape(t_b, t_m, s * p)
-        cols = s * p
-    # y_ref is acc_dtype (cast to the input dtype by the wrapper) so the
-    # cross-Q-tile accumulation never rounds through a low-precision type.
-    part = g.astype(y_ref.dtype)
+    f_refs, y_ref = refs[:n], refs[n]
+    t_m = x_ref.shape[1]
+    jq = pl.program_id(3) if direction == "bwd" else None
+    merge = _merges(t_m * ts if dense else t_m, interpret)
 
-    @pl.when(jq == 0)
-    def _init():
-        y_ref[...] = part
+    def sample(ib, carry):
+        fs = [f_ref[ib].astype(acc_dtype) for f_ref in f_refs]
+        if direction == "fwd":
+            w = _x_to_w(x_ref[ib].astype(acc_dtype), ts, dense)
+            for f in fs:
+                w = _step(w, f, acc_dtype, merge)
+            y = _w_to_y(w, t_m, ts, dense).reshape(y_ref.shape[1:])
+            y_ref[ib] = y.astype(y_ref.dtype)
+            return carry
+        w = _y_to_w(x_ref[ib].astype(acc_dtype), ts, dense)
+        for f in reversed(fs):
+            w = _step_t(w, f, acc_dtype, merge)
+        # y_ref is acc_dtype (cast to the input dtype by the wrapper) so the
+        # cross-Q-tile accumulation never rounds through a low-precision type.
+        part = _w_to_x(w, t_m, ts, dense).astype(y_ref.dtype)
 
-    @pl.when(jq > 0)
-    def _acc():
-        y_ref[...] += part
+        @pl.when(jq == 0)
+        def _init():
+            y_ref[ib] = part
+
+        @pl.when(jq > 0)
+        def _acc():
+            y_ref[ib] += part
+
+        return carry
+
+    jax.lax.fori_loop(0, x_ref.shape[0], sample, 0)
 
 
 def _q_tiling(qs, t_qs, n):
@@ -469,6 +590,176 @@ def _q_tiling(qs, t_qs, n):
         return (jq // strides[i]) % nq[i]
 
     return math.prod(nq), q_digit
+
+
+def _states(ps, qs, direction):
+    """Row counts R of the working matrix through the chain (per slice)."""
+    r = math.prod(ps) if direction == "fwd" else math.prod(qs)
+    out = [r]
+    pairs = zip(ps, qs) if direction == "fwd" else reversed(list(zip(ps, qs)))
+    for p, q in pairs:
+        r = r // p * q if direction == "fwd" else r // q * p
+        out.append(r)
+    return out
+
+
+def _work_bytes(r: int, t_m: int, ts: int, dense: bool, acc_bytes: int) -> int:
+    shape = (r, t_m * ts) if dense else (r * ts, t_m)
+    return _tiled_bytes(shape, acc_bytes)
+
+
+def _step_bytes(r_in, p, q, t_m, ts, dense, acc_bytes) -> int:
+    """Live VMEM of one factor step: its input and output working matrices
+    plus the relayout / broadcast temporaries of the GEMM it lowers to."""
+    r_out = r_in // p * q
+    w_in = _work_bytes(r_in, t_m, ts, dense, acc_bytes)
+    w_out = _work_bytes(r_out, t_m, ts, dense, acc_bytes)
+    lanes = t_m * ts if dense else t_m
+    if lanes % LANE == 0:
+        return 2 * (w_in + w_out)
+    s = (r_in if dense else r_in * ts) // p
+    temps = s * (
+        _tiled_bytes((p, lanes), acc_bytes)
+        + _tiled_bytes((q, p), acc_bytes)
+        + 2 * _tiled_bytes((q, lanes), acc_bytes)
+    )
+    return w_in + w_out + temps
+
+
+def chain_vmem_bytes(
+    t_b: int, t_m: int, t_k: int, ps, qs, *, direction: str, flat: bool,
+    in_bytes: int, out_bytes: int, acc_bytes: int = 4, grad: bool = False,
+) -> int:
+    """VMEM one grid step of the chain (or stage-gradient) kernel needs, with
+    every buffer padded to (sublane, 128) tiles: the double-buffered blocks
+    the pipeline streams, plus the live working set of one sample (blocks are
+    walked a sample at a time).  The emitter's legality check and the planner
+    both read this one model."""
+    ps, qs = tuple(ps), tuple(qs)
+    pprod, qprod = math.prod(ps), math.prod(qs)
+    ts = t_k // pprod
+    dense = not flat or ts % LANE == 0
+    x_blk = _tiled_bytes((t_m, t_k), in_bytes)
+    y_blk = _tiled_bytes((t_m, qprod * ts) if flat else (t_m, qprod, ts), in_bytes)
+    f_blk = sum(_tiled_bytes((p, q), in_bytes) for p, q in zip(ps, qs))
+    x_acc = _tiled_bytes((t_m, t_k), acc_bytes)
+    y_acc = _tiled_bytes((t_m, qprod * ts), acc_bytes)
+    if grad:
+        blocks = 2 * x_blk + y_blk + f_blk + x_acc + f_blk * acc_bytes // in_bytes
+        states = _states(ps, qs, "fwd")
+        kept = sum(_work_bytes(r, t_m, ts, dense, acc_bytes) for r in states)
+        steps = max(
+            _step_bytes(r, p, q, t_m, ts, dense, acc_bytes)
+            for r, p, q in zip(states, ps, qs)
+        )
+        live = kept + steps + x_acc + y_acc
+    else:
+        if direction == "fwd":
+            blocks = x_blk + f_blk + _tiled_bytes(
+                (t_m, qprod * ts) if flat else (t_m, qprod, ts), out_bytes
+            )
+            pairs = list(zip(_states(ps, qs, "fwd"), ps, qs))
+        else:
+            blocks = y_blk + f_blk + _tiled_bytes((t_m, t_k), out_bytes)
+            states = _states(ps, qs, "bwd")
+            pairs = list(zip(states, reversed(qs), reversed(ps)))
+        steps = max(
+            _step_bytes(r, a, b, t_m, ts, dense, acc_bytes) for r, a, b in pairs
+        )
+        live = steps + x_acc + y_acc
+    return 2 * t_b * blocks + live
+
+
+def tpu_block_error(
+    b: int, m: int, k: int, ps, qs, t_m: int, t_k: int, t_qs, itemsize: int
+) -> str | None:
+    """Why a chain tiling is not a legal Mosaic kernel, or None when it is.
+
+    A block's last two dims must be tile multiples or the full extent: rows
+    ``t_m`` a multiple of the sublane tile (or all of M), and the slice
+    count ``ts = t_k / prod(P)`` of a K-tiled block a multiple of 128 — the
+    output block then is (t_m, prod(Q), ts).  The kernels tile Q only in
+    interpret mode (a Q-tiled output block has no legal relayout)."""
+    pprod = math.prod(ps)
+    ts, s_out = t_k // pprod, k // pprod
+    if t_m != m and t_m % _sublane(itemsize):
+        return f"t_m={t_m} is neither M={m} nor a multiple of {_sublane(itemsize)}"
+    if ts != s_out and ts % LANE:
+        return f"K-tile slice count ts={ts} is not a multiple of {LANE}"
+    if t_qs is not None and tuple(t_qs) != tuple(qs):
+        return f"Q-tiled blocks t_qs={tuple(t_qs)} have no Mosaic layout"
+    return None
+
+
+def legal_tiles(
+    direction: str, b: int, m: int, k: int, ps, qs, *, t_b: int, t_m: int,
+    itemsize: int, acc_bytes: int = 4, grad: bool = False,
+    budget_bytes: int | None = None,
+) -> tuple[int, int, int] | None:
+    """The (t_b, t_m, t_k) a chain (or stage-gradient) kernel runs with on
+    the chip for a (b, m, k) problem, or None when no legal tiling fits the
+    VMEM budget — the stage then runs on the XLA executor.
+
+    Legal rows are M or multiples of the sublane tile; legal K-tiles are K
+    or ``prod(P) * ts`` with ts a multiple of 128.  Among those that fit,
+    prefer lane-dense working matrices, then the most work per grid step (up
+    to 2^18 elements: past that the pipeline has few steps to overlap DMA
+    with, and the unrolled in-kernel relayouts compile slowly), then rows
+    closest to the planner's ``t_m``."""
+    budget = VMEM_BUDGET_ELEMS * 4 if budget_bytes is None else budget_bytes
+    pprod = math.prod(ps)
+    s_out = k // pprod
+    t_ms = [d for d in _divisors(m) if d % _sublane(itemsize) == 0 or d == m]
+    t_ks = sorted({k} | {pprod * d for d in _divisors(s_out) if d % LANE == 0})
+    t_bs = [d for d in _divisors(b) if d <= max(1, t_b)]
+    best, best_key = None, None
+    for tm in t_ms:
+        for tk in t_ks:
+            ts = tk // pprod
+            flat = tk == k
+            for tb in t_bs:
+                nbytes = chain_vmem_bytes(
+                    tb, tm, tk, ps, qs, direction=direction, flat=flat,
+                    in_bytes=itemsize, out_bytes=itemsize, acc_bytes=acc_bytes,
+                    grad=grad,
+                )
+                if nbytes > budget:
+                    continue
+                lanes = tm * ts if (not flat or ts % LANE == 0) else tm
+                key = (
+                    lanes % LANE == 0,
+                    min(tb * tm * tk, 1 << 18),
+                    -abs(math.log2(tm / max(1, t_m))),
+                    -tb * tm * tk,
+                )
+                if best_key is None or key > best_key:
+                    best, best_key = (tb, tm, tk), key
+    return best
+
+
+def _compiler_params(interpret: bool):
+    if interpret:
+        return None
+    from jax.experimental.pallas import tpu as pltpu
+
+    from . import hardware
+
+    limit = hardware.tpu_spec().vmem_bytes * 3 // 4
+    return pltpu.CompilerParams(vmem_limit_bytes=int(limit))
+
+
+def _check_tiles(b, m, k, ps, qs, t_b, t_m, t_k, t_qs, itemsize, interpret):
+    pprod = math.prod(ps)
+    if t_k % pprod:
+        raise LoweringError(f"T_K={t_k} must be a multiple of prod(P)={pprod}")
+    if b % t_b or m % t_m or k % t_k:
+        raise LoweringError(
+            f"tiles must divide dims: {(b, m, k)} vs {(t_b, t_m, t_k)}"
+        )
+    if not interpret:
+        why = tpu_block_error(b, m, k, ps, qs, t_m, t_k, t_qs, itemsize)
+        if why is not None:
+            raise LoweringError(f"illegal TPU block: {why}")
 
 
 @functools.partial(
@@ -497,7 +788,8 @@ def chain_pallas(
     ``(B, M, prod(Q) * K/prod(P))`` chain output.  ``direction="bwd"``:
     ``x`` is the cotangent at C = prod(Q)*S, returns dX ``(B, M, prod(P)*S)``.
     The grid is always ``(B/t_b, M/t_m, Q-tiles, K/t_k)`` (Q-tiles innermost
-    for "bwd": the sequential accumulation axis).
+    for "bwd": the sequential accumulation axis).  Compiled (not
+    interpreted), the tiling must pass ``tpu_block_error``.
     """
     acc = _resolve_acc(acc_dtype, x.dtype)
     b, m, cols = x.shape
@@ -530,38 +822,56 @@ def chain_pallas(
         raise LoweringError(f"t_qs needs one entry per factor: {t_qs} vs {n}")
     if any(q % t for q, t in zip(qs, t_qs)):
         raise LoweringError(f"t_qs must divide factor Q dims: {t_qs} vs {qs}")
-    # Fusion validity: every slice of every fused stage stays inside the tile.
-    if t_k % pprod:
-        raise LoweringError(f"T_K={t_k} must be a multiple of prod(P)={pprod}")
-    growth_fn = fused_growth if direction == "fwd" else transposed_growth
-    growth = growth_fn(ps, qs, t_qs)
-    if t_b * t_m * t_k * growth > vmem_budget_elems:
+    _check_tiles(
+        b, m, k, ps, qs, t_b, t_m, t_k, t_qs, x.dtype.itemsize, interpret
+    )
+    q_full = t_qs == qs
+    flat = q_full and t_k == k
+    ts = t_k // pprod
+    dense = not flat or ts % LANE == 0
+    need = chain_vmem_bytes(
+        t_b, t_m, t_k, ps, t_qs, direction=direction, flat=flat,
+        in_bytes=x.dtype.itemsize, out_bytes=x.dtype.itemsize,
+        acc_bytes=jnp.dtype(acc).itemsize,
+    )
+    if need > vmem_budget_elems * 4:
         raise VmemOverflowError(
-            f"tile {t_b}x{t_m}x{t_k} (growth {growth:.2f}) exceeds VMEM "
-            f"budget; reduce t_b / t_m / t_k or tile Q via t_qs"
-        )
-    if b % t_b or m % t_m or k % t_k:
-        raise LoweringError(
-            f"tiles must divide dims: {(b, m, k)} vs {(t_b, t_m, t_k)}"
+            f"tile {t_b}x{t_m}x{t_k} needs {need} B of VMEM (padded), over "
+            f"the {vmem_budget_elems * 4} B budget; reduce t_b / t_m / t_k"
         )
 
-    ts_out = t_k // pprod
     # Composite Q-tile grid axis: one mixed-radix digit per factor, factor 0
     # (applied first) minor — matching the output layout (q_n, ..., q_1, s).
     nq_tiles, q_digit = _q_tiling(qs, t_qs, n)
-    # The (B, M, Q_{n-1}, ..., Q_0, S) view: row-major it flattens to the
-    # FastKron layout (B, M, prod(Q)*S); each Q axis is tiled by its own digit.
-    q_view = (b, m) + tuple(reversed(qs)) + (s_out,)
-    q_block = (t_b, t_m) + tuple(reversed(t_qs)) + (ts_out,)
+    # The y-side view: flat (B, M, prod(Q)*S) for whole-K blocks, else
+    # (B, M, prod(Q), S) with (t_m, prod(Q), ts) blocks, or — Q-tiled,
+    # interpret only — one axis per Q digit, each tiled by its own digit.
+    if flat:
+        y_view, y_block = (b, m, qprod * s_out), (t_b, t_m, qprod * s_out)
 
-    if direction == "fwd":
-        grid = (b // t_b, m // t_m, nq_tiles, k // t_k)
+        def y_index(ib, im, jq, j):
+            return (ib, im, 0)
+    elif q_full:
+        y_view, y_block = (b, m, qprod, s_out), (t_b, t_m, qprod, ts)
 
-        def q_index(ib, im, jq, j):
+        def y_index(ib, im, jq, j):
+            return (ib, im, 0, j)
+    else:
+        y_view = (b, m) + tuple(reversed(qs)) + (s_out,)
+        y_block = (t_b, t_m) + tuple(reversed(t_qs)) + (ts,)
+
+        def y_index(ib, im, jq, j):
             return (ib, im) + tuple(
                 q_digit(jq, i) for i in reversed(range(n))
             ) + (j,)
 
+    kernel = functools.partial(
+        _chain_kernel, n=n, ts=ts, dense=dense, direction=direction,
+        acc_dtype=acc, interpret=interpret,
+    )
+    params = _compiler_params(interpret)
+    if direction == "fwd":
+        grid = (b // t_b, m // t_m, nq_tiles, k // t_k)
         in_specs = [
             pl.BlockSpec((t_b, t_m, t_k), lambda ib, im, jq, j: (ib, im, j))
         ]
@@ -573,26 +883,22 @@ def chain_pallas(
                 )
             )
         out = pl.pallas_call(
-            functools.partial(
-                _chain_kernel, ps=ps, qs=t_qs, direction="fwd", acc_dtype=acc
-            ),
+            kernel,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(q_block, q_index),
-            out_shape=jax.ShapeDtypeStruct(q_view, x.dtype),
+            out_specs=pl.BlockSpec(y_block, y_index),
+            out_shape=jax.ShapeDtypeStruct(y_view, x.dtype),
             interpret=interpret,
+            compiler_params=params,
+            name=KERNEL_NAMES[0],
         )(x, *factors)
         return out.reshape(b, m, qprod * s_out)
 
     # bwd: Q innermost — the sequential accumulation dim.
     grid = (b // t_b, m // t_m, k // t_k, nq_tiles)
-
-    def q_index(ib, im, j, jq):
-        return (ib, im) + tuple(
-            q_digit(jq, i) for i in reversed(range(n))
-        ) + (j,)
-
-    in_specs = [pl.BlockSpec(q_block, q_index)]
+    in_specs = [
+        pl.BlockSpec(y_block, lambda ib, im, j, jq: y_index(ib, im, jq, j))
+    ]
     for i in range(n):
         in_specs.append(
             pl.BlockSpec(
@@ -601,9 +907,7 @@ def chain_pallas(
             )
         )
     out = pl.pallas_call(
-        functools.partial(
-            _chain_kernel, ps=ps, qs=t_qs, direction="bwd", acc_dtype=acc
-        ),
+        kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
@@ -611,7 +915,9 @@ def chain_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((b, m, k), acc),
         interpret=interpret,
-    )(x.reshape(q_view), *factors)
+        compiler_params=params,
+        name=KERNEL_NAMES[1],
+    )(x.reshape(y_view), *factors)
     return out.astype(x.dtype)
 
 
@@ -621,65 +927,48 @@ def chain_pallas(
 
 
 def _grad_kernel(
-    x_ref, dy_ref, *refs, ps: tuple[int, ...], qs: tuple[int, ...], acc_dtype
+    x_ref, dy_ref, *refs, n: int, ts: int, dense: bool, acc_dtype,
+    interpret: bool,
 ):
     """Full stage backward: rematerialize the forward chain in VMEM, then
     walk the transposed chain computing the input gradient and every factor
-    gradient.  Per factor ONE in-VMEM relayout of the gradient tile is shared
-    by the factor-gradient GEMM (``U^T G``) and the chain-step GEMM
-    (``G F^T``).  Factor grads are per batch block: they accumulate over the
-    (M, K) grid for a fixed batch block only (batch is the outermost grid
-    axis, sequential on TPU), which reduces to the whole-grid accumulation
-    of the unbatched kernel when B = t_b = 1.
+    gradient.  Per factor ONE relayout of the gradient tile is shared by the
+    factor-gradient GEMM and the chain-step GEMM (``_step_t``).  Factor
+    grads are per batch block: they accumulate over the (M, K) grid for a
+    fixed batch block only (batch is the outermost grid axis, sequential on
+    TPU), which reduces to the whole-grid accumulation of the unbatched
+    kernel when B = t_b = 1.
     """
-    f_refs = refs[: len(ps)]
-    dx_ref = refs[len(ps)]
-    df_refs = refs[len(ps) + 1 :]
+    f_refs = refs[:n]
+    dx_ref = refs[n]
+    df_refs = refs[n + 1 :]
     im, j = pl.program_id(1), pl.program_id(2)
     first = jnp.logical_and(im == 0, j == 0)
-    t_b, t_m = x_ref.shape[0], x_ref.shape[1]
-    # In-VMEM rematerialization of the forward chain (stage-local residuals).
-    us = []
-    y = x_ref[...].astype(acc_dtype)
-    cols = y.shape[2]
-    for f_ref, p, q in zip(f_refs, ps, qs):
-        us.append(y)
-        s = cols // p
-        acc = jax.lax.dot_general(
-            y.reshape(t_b, t_m * s, p), f_ref[...], (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=acc_dtype,
-        )
-        y = jnp.swapaxes(acc.reshape(t_b, t_m, s, q), 2, 3).reshape(
-            t_b, t_m, q * s
-        )
-        cols = q * s
-    g = dy_ref[...].reshape(t_b, t_m, -1).astype(acc_dtype)
-    cols = g.shape[2]
-    for idx in reversed(range(len(f_refs))):
-        p, q = ps[idx], qs[idx]
-        s = cols // q
-        g2 = jnp.swapaxes(g.reshape(t_b, t_m, q, s), 2, 3).reshape(
-            t_b, t_m * s, q
-        )
-        u2 = us[idx].reshape(t_b, t_m * s, p)
-        df_part = jax.lax.dot_general(
-            u2, g2, (((1,), (1,)), ((0,), (0,))), preferred_element_type=acc_dtype
-        )  # (t_b, p, q)
+    t_m = x_ref.shape[1]
+    merge = _merges(t_m * ts if dense else t_m, interpret)
 
-        @pl.when(first)
-        def _init(df_ref=df_refs[idx], df_part=df_part):
-            df_ref[...] = df_part
+    def sample(ib, carry):
+        fs = [f_ref[ib].astype(acc_dtype) for f_ref in f_refs]
+        # In-VMEM rematerialization of the forward chain (stage-local).
+        us = [_x_to_w(x_ref[ib].astype(acc_dtype), ts, dense)]
+        for f in fs[:-1]:
+            us.append(_step(us[-1], f, acc_dtype, merge))
+        g = _y_to_w(dy_ref[ib].astype(acc_dtype), ts, dense)
+        for idx in reversed(range(n)):
+            df_part, g = _step_t(g, fs[idx], acc_dtype, merge, u=us[idx])
 
-        @pl.when(jnp.logical_not(first))
-        def _acc(df_ref=df_refs[idx], df_part=df_part):
-            df_ref[...] += df_part
+            @pl.when(first)
+            def _init(df_ref=df_refs[idx], df_part=df_part):
+                df_ref[ib] = df_part
 
-        g = jax.lax.dot_general(
-            g2, f_refs[idx][...], (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=acc_dtype,
-        ).reshape(t_b, t_m, s * p)
-        cols = s * p
-    dx_ref[...] = g.astype(dx_ref.dtype)
+            @pl.when(jnp.logical_not(first))
+            def _acc(df_ref=df_refs[idx], df_part=df_part):
+                df_ref[ib] += df_part
+
+        dx_ref[ib] = _w_to_x(g, t_m, ts, dense).astype(dx_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, x_ref.shape[0], sample, 0)
 
 
 @functools.partial(
@@ -708,6 +997,7 @@ def grad_pallas(
     """
     acc = _resolve_acc(acc_dtype, dy.dtype)
     b, m, k = x.shape
+    n = len(factors)
     ps = tuple(int(f.shape[1]) for f in factors)
     qs = tuple(int(f.shape[2]) for f in factors)
     for f in factors:
@@ -723,31 +1013,37 @@ def grad_pallas(
     t_b = min(t_b, b)
     t_m = min(t_m, m)
     t_k = min(t_k or k, k)
-    if t_k % pprod:
-        raise LoweringError(f"T_K={t_k} must be a multiple of prod(P)={pprod}")
-    # Live set: all forward intermediates of the tile chain plus the gradient
-    # tile — a sum over chain states, not just the max.
-    cols = float(t_k)
-    live = cols
-    for p, q in zip(ps, qs):
-        cols = cols / p * q
-        live += cols
-    if t_b * t_m * (live + cols) > vmem_budget_elems:
+    _check_tiles(b, m, k, ps, qs, t_b, t_m, t_k, None, x.dtype.itemsize, interpret)
+    flat = t_k == k
+    ts = t_k // pprod
+    dense = not flat or ts % LANE == 0
+    need = chain_vmem_bytes(
+        t_b, t_m, t_k, ps, qs, direction="fwd", flat=flat,
+        in_bytes=x.dtype.itemsize, out_bytes=x.dtype.itemsize,
+        acc_bytes=jnp.dtype(acc).itemsize, grad=True,
+    )
+    if need > vmem_budget_elems * 4:
         raise VmemOverflowError(
-            f"bwd tile {t_b}x{t_m}x{t_k} live set "
-            f"{int(t_b * t_m * (live + cols))} elems exceeds VMEM budget; "
-            f"reduce t_b / t_k or split the stage"
-        )
-    if b % t_b or m % t_m or k % t_k:
-        raise LoweringError(
-            f"tiles must divide dims: {(b, m, k)} vs {(t_b, t_m, t_k)}"
+            f"bwd tile {t_b}x{t_m}x{t_k} live set needs {need} B of VMEM "
+            f"(padded), over the {vmem_budget_elems * 4} B budget; reduce "
+            f"t_b / t_k or split the stage"
         )
 
-    ts_out = t_k // pprod
     grid = (b // t_b, m // t_m, k // t_k)
+    if flat:
+        dy_view, dy_block = (b, m, qprod * s_out), (t_b, t_m, qprod * s_out)
+
+        def dy_index(ib, im, j):
+            return (ib, im, 0)
+    else:
+        dy_view, dy_block = (b, m, qprod, s_out), (t_b, t_m, qprod, ts)
+
+        def dy_index(ib, im, j):
+            return (ib, im, 0, j)
+
     in_specs = [
         pl.BlockSpec((t_b, t_m, t_k), lambda ib, im, j: (ib, im, j)),
-        pl.BlockSpec((t_b, t_m, qprod, ts_out), lambda ib, im, j: (ib, im, 0, j)),
+        pl.BlockSpec(dy_block, dy_index),
     ]
     for p, q in zip(ps, qs):
         in_specs.append(pl.BlockSpec((t_b, p, q), lambda ib, im, j: (ib, 0, 0)))
@@ -757,13 +1053,18 @@ def grad_pallas(
         out_specs.append(pl.BlockSpec((t_b, p, q), lambda ib, im, j: (ib, 0, 0)))
         out_shapes.append(jax.ShapeDtypeStruct((b, p, q), acc))
     outs = pl.pallas_call(
-        functools.partial(_grad_kernel, ps=ps, qs=qs, acc_dtype=acc),
+        functools.partial(
+            _grad_kernel, n=n, ts=ts, dense=dense, acc_dtype=acc,
+            interpret=interpret,
+        ),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
-    )(x, dy.reshape(b, m, qprod, s_out), *factors)
+        compiler_params=_compiler_params(interpret),
+        name=KERNEL_NAMES[2],
+    )(x, dy.reshape(dy_view), *factors)
     return outs[0], tuple(outs[1:])
 
 
@@ -897,26 +1198,24 @@ def _grad_tile(us_first, g, factors, acc):
             t_m = g.shape[0]
             g2 = jnp.swapaxes(g.reshape(t_m, q, s), 1, 2).reshape(t_m * s, q)
             u2 = us[idx].reshape(t_m * s, p)
-            dfs[idx] = jax.lax.dot_general(
-                u2.astype(acc), g2.astype(acc), (((0,), (0,)), ((), ())),
-                preferred_element_type=acc,
+            dfs[idx] = _dot(
+                u2.astype(acc), g2.astype(acc), (((0,), (0,)), ((), ())), acc
             )
-            g = jax.lax.dot_general(
-                g2, f, (((1,), (1,)), ((), ())), preferred_element_type=acc
-            ).reshape(t_m, s * p).astype(g.dtype)
+            g = _dot(g2, f, (((1,), (1,)), ((), ())), acc).reshape(
+                t_m, s * p
+            ).astype(g.dtype)
         else:
             t_b, t_m = g.shape[0], g.shape[1]
             g2 = jnp.swapaxes(g.reshape(t_b, t_m, q, s), 2, 3).reshape(
                 t_b, t_m * s, q
             )
             u2 = us[idx].reshape(t_b, t_m * s, p)
-            dfs[idx] = jax.lax.dot_general(
-                u2.astype(acc), g2.astype(acc), (((1,), (1,)), ((0,), (0,))),
-                preferred_element_type=acc,
+            dfs[idx] = _dot(
+                u2.astype(acc), g2.astype(acc), (((1,), (1,)), ((0,), (0,))), acc
             )  # (t_b, p, q)
-            g = jax.lax.dot_general(
-                g2, f, (((2,), (2,)), ((0,), (0,))), preferred_element_type=acc
-            ).reshape(t_b, t_m, s * p).astype(g.dtype)
+            g = _dot(g2, f, (((2,), (2,)), ((0,), (0,))), acc).reshape(
+                t_b, t_m, s * p
+            ).astype(g.dtype)
         cols = s * p
     return dfs, g
 
@@ -1003,6 +1302,31 @@ def _effective(instr: StageInstr, fs: tuple[jax.Array, ...]):
     return instr.direction, fs, instr.t_qs
 
 
+def stage_tiles(
+    instr: StageInstr, y_shape, dtype, *, grad: bool = False,
+    vmem_budget_elems: int = VMEM_BUDGET_ELEMS,
+) -> tuple[int, int, int] | None:
+    """The legal (t_b, t_m, t_k) the COMPILED Pallas kernel runs ``instr``
+    with on an operand of ``y_shape`` ((M, C), or (B, M, C) batched), or None
+    when no legal tiling fits VMEM and the stage runs on the XLA executor.
+    Decided from shapes and dtype alone, before anything is compiled.
+    ``grad=True`` asks about the stage-backward kernel (``y_shape`` is then
+    the stage input's)."""
+    ps, qs = instr.ps, instr.qs
+    if instr.kind == PREKRON:
+        ps, qs = (math.prod(ps),), (math.prod(qs),)
+    b, m, cols = (1,) * (3 - len(y_shape)) + tuple(int(d) for d in y_shape)
+    direction = "fwd" if grad else instr.direction
+    k = cols if direction == "fwd" else cols // math.prod(qs) * math.prod(ps)
+    dtype = jnp.dtype(dtype)
+    return legal_tiles(
+        direction, b, m, k, ps, qs, t_b=instr.t_b or 1, t_m=instr.t_m,
+        itemsize=dtype.itemsize,
+        acc_bytes=jnp.dtype(_resolve_acc(instr.acc_dtype, dtype)).itemsize,
+        grad=grad, budget_bytes=vmem_budget_elems * 4,
+    )
+
+
 def run_stage(
     y: jax.Array,
     stage_factors: Sequence[jax.Array],
@@ -1015,9 +1339,11 @@ def run_stage(
     """Execute one chain instruction on ``y``.
 
     ``stage_factors`` are the stage's factor arrays in application order —
-    2-D when ``instr.t_b is None``, per-sample 3-D otherwise.  Raises
-    ``VmemOverflowError`` (a ``ValueError``) when the Pallas tiling cannot
-    hold the stage in VMEM (callers fall back to per-factor execution).
+    2-D when ``instr.t_b is None``, per-sample 3-D otherwise.  Compiled,
+    the instruction runs with the legal tiles of ``stage_tiles`` (the XLA
+    executor when there are none).  Interpreted, it runs with its own tiles
+    and raises ``VmemOverflowError`` (a ``ValueError``) when they cannot
+    hold the stage in VMEM.
     """
     chaos.maybe_fail("stage_execute")
     # One truthiness check when telemetry is off (span() returns a shared
@@ -1033,15 +1359,28 @@ def run_stage(
             )
         chaos.maybe_fail("pallas_lowering")
         ip = _interpret_default(interpret)
+        t_b, t_m, t_k = instr.t_b or 1, instr.t_m, instr.t_k
+        if not ip:
+            # Compiled: run the legal tiling (``stage_tiles``), or the XLA
+            # executor when the shape has none — never a failed compile.
+            tiles = stage_tiles(
+                instr, y.shape, y.dtype, vmem_budget_elems=vmem_budget_elems
+            )
+            if tiles is None:
+                return _chain_xla(
+                    y, fs, t_m=instr.t_m, t_b=instr.t_b, direction=direction,
+                    acc_dtype=instr.acc_dtype,
+                )
+            (t_b, t_m, t_k), t_qs = tiles, None
         if instr.t_b is None:
             out = chain_pallas(
-                y[None], *(f[None] for f in fs), t_b=1, t_m=instr.t_m,
-                t_k=instr.t_k, t_qs=t_qs, direction=direction, interpret=ip,
+                y[None], *(f[None] for f in fs), t_b=1, t_m=t_m, t_k=t_k,
+                t_qs=t_qs, direction=direction, interpret=ip,
                 acc_dtype=instr.acc_dtype, vmem_budget_elems=vmem_budget_elems,
             )
             return out[0]
         return chain_pallas(
-            y, *fs, t_b=instr.t_b, t_m=instr.t_m, t_k=instr.t_k, t_qs=t_qs,
+            y, *fs, t_b=t_b, t_m=t_m, t_k=t_k, t_qs=t_qs,
             direction=direction, interpret=ip, acc_dtype=instr.acc_dtype,
             vmem_budget_elems=vmem_budget_elems,
         )
@@ -1062,8 +1401,9 @@ def run_stage_grad(
     ``u`` is the stage input, ``g`` the stage output cotangent; ``instr`` is
     the FORWARD instruction (its transpose is implied).  Factor grads are
     returned in application order, accumulated in the stage's acc dtype
-    (callers cast).  Raises ``VmemOverflowError`` (a ``ValueError``) when
-    the one-kernel Pallas backward cannot hold the stage's live set in VMEM.
+    (callers cast).  Tiles are chosen as in ``run_stage``; interpreted, it
+    raises ``VmemOverflowError`` (a ``ValueError``) when the instruction's
+    own tiles cannot hold the stage's live set in VMEM.
     """
     chaos.maybe_fail("stage_execute")
     with telemetry.span("stage_grad", kind=instr.kind):
@@ -1077,20 +1417,31 @@ def run_stage_grad(
             return guard.check_finite(dx, "run_stage_grad"), dfs
         chaos.maybe_fail("pallas_lowering")
         ip = _interpret_default(interpret)
+        t_b, t_m, t_k = instr.t_b or 1, instr.t_m, instr.t_k
+        if not ip:
+            tiles = stage_tiles(
+                instr, u.shape, u.dtype, grad=True,
+                vmem_budget_elems=vmem_budget_elems,
+            )
+            if tiles is None:
+                dx, dfs = _grad_xla(
+                    u, g, fs, t_m=instr.t_m, t_b=instr.t_b,
+                    acc_dtype=instr.acc_dtype,
+                )
+                return guard.check_finite(dx, "run_stage_grad"), dfs
+            t_b, t_m, t_k = tiles
         if instr.t_b is None:
             dx, dfs = grad_pallas(
-                u[None], g[None], *(f[None] for f in fs), t_b=1,
-                t_m=instr.t_m, t_k=instr.t_k, interpret=ip,
-                acc_dtype=instr.acc_dtype,
+                u[None], g[None], *(f[None] for f in fs), t_b=1, t_m=t_m,
+                t_k=t_k, interpret=ip, acc_dtype=instr.acc_dtype,
                 vmem_budget_elems=vmem_budget_elems,
             )
             return guard.check_finite(dx[0], "run_stage_grad"), tuple(
                 d[0] for d in dfs
             )
         dx, dfs = grad_pallas(
-            u, g, *fs, t_b=instr.t_b, t_m=instr.t_m, t_k=instr.t_k,
-            interpret=ip, acc_dtype=instr.acc_dtype,
-            vmem_budget_elems=vmem_budget_elems,
+            u, g, *fs, t_b=t_b, t_m=t_m, t_k=t_k, interpret=ip,
+            acc_dtype=instr.acc_dtype, vmem_budget_elems=vmem_budget_elems,
         )
         return guard.check_finite(dx, "run_stage_grad"), dfs
 
@@ -1159,6 +1510,11 @@ __all__ = [
     "split_slabs",
     "chain_pallas",
     "grad_pallas",
+    "chain_vmem_bytes",
+    "legal_tiles",
+    "stage_tiles",
+    "tpu_block_error",
+    "KERNEL_NAMES",
     "fused_growth",
     "transposed_growth",
     "max_n_fused",

@@ -1,60 +1,22 @@
-"""Pallas TPU kernel for one FastKron sliced multiply (contributions C1+C2).
+"""One FastKron sliced multiply (contributions C1+C2) as a chain of one.
 
 Semantics: for ``X: (M, K)`` and ``F: (P, Q)`` with ``S = K // P`` compute
 
     Y[m, q*S + s] = sum_p X[m, s*P + p] * F[p, q]
 
-The TPU-native realization of the paper's "write at the final index" insight:
-declare the output as the 3-D view ``(M, Q, S)`` — row-major it flattens to
-exactly ``(M, Q*S)`` with the FastKron layout — and tile it with a regular
-``BlockSpec`` of shape ``(T_M, T_Q, T_S)``.  The strided scatter the CUDA
-kernel performs by hand becomes a *contiguous* block store; the layout fix
-happens in registers between the MXU and the store, never as a second pass
-over HBM.
-
-Tiling (mirrors the paper's {T_M, T_K, T_Q} thread-block tile):
-  grid = (M/T_M, S/T_S, Q/T_Q)
-  X block   (T_M, T_S*P)  — 2-D so the minor-most dim stays long/lane-aligned
-  F block   (P, T_Q)
-  Y block   (T_M, T_Q, T_S) of the (M, Q, S) view
-
-The per-thread register tile (R_K, R_Q, R_P) of the CUDA kernel has no direct
-analogue: VREG scheduling belongs to Mosaic.  Our levers are T_M/T_S/T_Q,
-searched by core/autotune.py.  Shift caching (C2's bank-conflict fix) is
-replaced by layout choice — see DESIGN.md §2.
+The kernel is the emitter's chain template (``emit.chain_pallas``) with a
+single factor: its output block is the (T_M, Q, T_S) tile of the FastKron
+layout, so the strided scatter of the CUDA kernel is a contiguous block
+store.  Tiling mirrors the paper's {T_M, T_K, T_Q} thread-block tile:
+``t_s`` slices per block (T_K = t_s * P) and ``t_q`` columns of F.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from . import emit
 
 
-def _sliced_kernel(x_ref, f_ref, y_ref, *, p: int, acc_dtype):
-    """One (T_M, T_S*P) x (P, T_Q) -> (T_M, T_Q, T_S) sliced multiply."""
-    t_m, t_k = x_ref.shape
-    t_s = t_k // p
-    x = x_ref[...].reshape(t_m * t_s, p)
-    f = f_ref[...]
-    # MXU contraction over P; accumulate in f32.
-    acc = jax.lax.dot_general(
-        x,
-        f,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=acc_dtype,
-    )  # (T_M*T_S, T_Q)
-    t_q = f.shape[1]
-    acc = acc.reshape(t_m, t_s, t_q)
-    # In-VMEM relayout to the FastKron output order (m, q, s): this is the
-    # transpose the shuffle algorithm pays an HBM round-trip for.
-    y_ref[...] = jnp.swapaxes(acc, 1, 2).astype(y_ref.dtype)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("t_m", "t_s", "t_q", "interpret", "acc_dtype")
-)
 def sliced_multiply_pallas(
     x: jax.Array,
     f: jax.Array,
@@ -65,30 +27,17 @@ def sliced_multiply_pallas(
     interpret: bool = False,
     acc_dtype=None,
 ) -> jax.Array:
-    """Single sliced multiply via pallas_call.  Returns (M, Q*S)."""
-    if acc_dtype is None:
-        acc_dtype = jnp.promote_types(x.dtype, jnp.float32)
-    m, k = x.shape
+    """Single sliced multiply.  ``x: (M, K)``, ``f: (P, Q)`` -> (M, Q*S)."""
     p, q = f.shape
-    if k % p:
-        raise ValueError(f"K={k} not divisible by P={p}")
-    s = k // p
-    t_m = min(t_m, m)
+    if x.shape[1] % p:
+        raise ValueError(f"K={x.shape[1]} not divisible by P={p}")
+    s = x.shape[1] // p
     t_s = min(t_s or max(1, min(s, 512)), s)
-    t_q = min(t_q or q, q)
-    if m % t_m or s % t_s or q % t_q:
-        raise ValueError(f"tiles must divide dims: {(m, s, q)} vs {(t_m, t_s, t_q)}")
+    return emit.chain_pallas(
+        x[None], f[None], t_m=t_m, t_k=t_s * p,
+        t_qs=None if t_q is None else (t_q,), interpret=interpret,
+        acc_dtype=acc_dtype,
+    )[0]
 
-    grid = (m // t_m, s // t_s, q // t_q)
-    out = pl.pallas_call(
-        functools.partial(_sliced_kernel, p=p, acc_dtype=acc_dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((t_m, t_s * p), lambda i, j, l: (i, j)),
-            pl.BlockSpec((p, t_q), lambda i, j, l: (0, l)),
-        ],
-        out_specs=pl.BlockSpec((t_m, t_q, t_s), lambda i, j, l: (i, l, j)),
-        out_shape=jax.ShapeDtypeStruct((m, q, s), x.dtype),
-        interpret=interpret,
-    )(x, f)
-    return out.reshape(m, q * s)
+
+__all__ = ["sliced_multiply_pallas"]

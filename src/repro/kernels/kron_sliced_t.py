@@ -1,55 +1,22 @@
-"""Pallas TPU kernel for the TRANSPOSED sliced multiply — the backward of
-FastKron's C1 (beyond-paper: the paper only treats inference/forward).
+"""The TRANSPOSED sliced multiply — the backward of FastKron's C1
+(beyond-paper: the paper only treats inference/forward).
 
 The VJP of ``Y[m, q*S+s] = sum_p X[m, s*P+p] F[p, q]`` w.r.t. X is
 
     dX[m, s*P + p] = sum_q dY[m, q*S + s] * F[p, q]
 
-which is itself Kron-shaped: view dY as (M, Q, S) (the same output view the
-forward kernel writes) and contract the Q axis.  The BlockSpec mirror of
-kron_sliced.py: dY blocks are read as (T_M, T_Q, T_S) tiles of the 3-D
-view, dX written as contiguous (T_M, T_S*P) tiles — again no scatter, no
-transpose pass.
-
-Accumulation: the Q-tile grid dimension is innermost and sequential on
-TPU, so the kernel revisits its output block and accumulates across
-``l`` iterations (init at l == 0) — the standard Pallas reduction layout.
+which is the emitter's transposed chain of one factor
+(``emit.chain_pallas(direction="bwd")``): dY blocks are read in the FastKron
+layout, dX written as contiguous (T_M, T_S*P) tiles, and Q-tiles (``t_q``)
+accumulate across the innermost, sequential grid axis.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from . import emit
 
 
-def _sliced_t_kernel(dy_ref, f_ref, dx_ref, *, acc_dtype):
-    l = pl.program_id(2)
-    t_m, t_q, t_s = dy_ref.shape
-    p = f_ref.shape[0]
-    dy = dy_ref[...]  # (T_M, T_Q, T_S)
-    f = f_ref[...]    # (P, T_Q)
-    # (T_M*T_S, T_Q) x (T_Q, P) on the MXU
-    dy2 = jnp.swapaxes(dy, 1, 2).reshape(t_m * t_s, t_q)
-    part = jax.lax.dot_general(
-        dy2, jnp.swapaxes(f, 0, 1), (((1,), (0,)), ((), ())),
-        preferred_element_type=acc_dtype,
-    )  # (T_M*T_S, P)
-    part = part.reshape(t_m, t_s * p).astype(dx_ref.dtype)
-
-    @pl.when(l == 0)
-    def _init():
-        dx_ref[...] = part
-
-    @pl.when(l > 0)
-    def _acc():
-        dx_ref[...] += part
-
-
-@functools.partial(
-    jax.jit, static_argnames=("t_m", "t_s", "t_q", "interpret", "acc_dtype")
-)
 def sliced_multiply_t_pallas(
     dy: jax.Array,
     f: jax.Array,
@@ -61,32 +28,16 @@ def sliced_multiply_t_pallas(
     acc_dtype=None,
 ) -> jax.Array:
     """dX for one sliced multiply.  dy: (M, Q*S), f: (P, Q) -> (M, S*P)."""
-    if acc_dtype is None:
-        acc_dtype = jnp.promote_types(dy.dtype, jnp.float32)
-    m, l_cols = dy.shape
     p, q = f.shape
-    if l_cols % q:
-        raise ValueError(f"dY cols {l_cols} not divisible by Q={q}")
-    s = l_cols // q
-    t_m = min(t_m, m)
+    if dy.shape[1] % q:
+        raise ValueError(f"dY cols {dy.shape[1]} not divisible by Q={q}")
+    s = dy.shape[1] // q
     t_s = min(t_s or max(1, min(s, 512)), s)
-    t_q = min(t_q or q, q)
-    if m % t_m or s % t_s or q % t_q:
-        raise ValueError(f"tiles must divide dims: {(m, s, q)} vs {(t_m, t_s, t_q)}")
-
-    grid = (m // t_m, s // t_s, q // t_q)  # q innermost: accumulation dim
-    out = pl.pallas_call(
-        functools.partial(_sliced_t_kernel, acc_dtype=acc_dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((t_m, t_q, t_s), lambda i, j, l: (i, l, j)),
-            pl.BlockSpec((p, t_q), lambda i, j, l: (0, l)),
-        ],
-        out_specs=pl.BlockSpec((t_m, t_s * p), lambda i, j, l: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, s * p), dy.dtype),
-        interpret=interpret,
-    )(dy.reshape(m, q, s), f)
-    return out
+    return emit.chain_pallas(
+        dy[None], f[None], t_m=t_m, t_k=t_s * p,
+        t_qs=None if t_q is None else (t_q,), direction="bwd",
+        interpret=interpret, acc_dtype=acc_dtype,
+    )[0]
 
 
 __all__ = ["sliced_multiply_t_pallas"]

@@ -20,9 +20,9 @@ Since the StageProgram refactor the fused-chain execution lives in
 compatibility shims that build a one-instruction program and call the
 emitter.  Each warns once per process; the engine's hot paths call ``emit``
 directly and never enter them.  ``sliced_multiply`` / ``sliced_multiply_t``
-remain first-class: they dispatch the per-factor C1/C2 kernels
-(kron_sliced.py / kron_sliced_t.py) that the unfused baseline and the
-distributed per-iteration mode use.
+remain first-class: they run one-factor chain instructions (the C1/C2
+sliced multiply) that the unfused baseline and the distributed
+per-iteration mode use.
 """
 from __future__ import annotations
 
@@ -31,19 +31,11 @@ from typing import Sequence
 
 import jax
 
-from . import emit, kron_sliced, kron_sliced_t
+from . import emit
 from . import ref as _ref
 from .emit import XLA_CACHE_BUDGET_BYTES, acc_dtype_for, resolve_backend  # noqa: F401
 
 Backend = str  # "auto" | "xla" | "pallas"
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _interpret() -> bool:
-    return not _on_tpu()
 
 
 _SHIM_WARNED: set[str] = set()
@@ -67,6 +59,17 @@ _sliced_xla = jax.jit(lambda x, f: emit.sliced_apply(x, f))
 _sliced_t_xla = jax.jit(lambda dy, f: emit.sliced_apply_t(dy, f))
 
 
+def _one_factor(f, tiles, kind) -> emit.StageInstr:
+    """A one-factor chain instruction from (t_m, t_s, t_q) paper tiles."""
+    p, q = int(f.shape[0]), int(f.shape[1])
+    t_m, t_s, t_q = tiles or (8, None, None)
+    return emit.StageInstr(
+        kind=kind, ps=(p,), qs=(q,), t_m=t_m,
+        t_k=None if t_s is None else t_s * p,
+        t_qs=None if t_q is None else (t_q,),
+    )
+
+
 def sliced_multiply(
     x: jax.Array,
     f: jax.Array,
@@ -78,10 +81,7 @@ def sliced_multiply(
     b = resolve_backend(backend)
     if b == "xla":
         return _sliced_xla(x, f)
-    t_m, t_s, t_q = tiles or (8, None, None)
-    return kron_sliced.sliced_multiply_pallas(
-        x, f, t_m=t_m, t_s=t_s, t_q=t_q, interpret=_interpret()
-    )
+    return emit.run_stage(x, (f,), _one_factor(f, tiles, emit.MULTIPLY), backend=b)
 
 
 def sliced_multiply_t(
@@ -95,9 +95,8 @@ def sliced_multiply_t(
     b = resolve_backend(backend)
     if b == "xla":
         return _sliced_t_xla(dy, f)
-    t_m, t_s, t_q = tiles or (8, None, None)
-    return kron_sliced_t.sliced_multiply_t_pallas(
-        dy, f, t_m=t_m, t_s=t_s, t_q=t_q, interpret=_interpret()
+    return emit.run_stage(
+        dy, (f,), _one_factor(f, tiles, emit.TRANSPOSED_MULTIPLY), backend=b
     )
 
 

@@ -23,7 +23,7 @@ def report(arch: str, shape: str, multi_pod: bool = False, top: int = 15):
         (1,) if cell.shape.kind == "decode" else ())
     jitted = jax.jit(cell.fn, out_shardings=cell.out_shardings,
                      donate_argnums=donate)
-    with mesh:
+    with jax.set_mesh(mesh):
         txt = jitted.lower(*cell.in_specs).compile().as_text()
     comps = H._parse(txt)
 

@@ -65,7 +65,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str | None):
     )
     jitted = jax.jit(cell.fn, out_shardings=cell.out_shardings,
                      donate_argnums=donate)
-    with mesh:  # ambient mesh: activates the model's sharding constraints
+    # Ambient mesh: activates the model's sharding constraints.
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*cell.in_specs)
         t_lower = time.time() - t0
         t0 = time.time()
@@ -74,9 +75,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str | None):
 
     mem = compiled.memory_analysis()
     raw_cost = compiled.cost_analysis()
-    # jax < 0.5 returns a one-element list of dicts, newer versions the dict.
-    if isinstance(raw_cost, (list, tuple)):
-        raw_cost = raw_cost[0] if raw_cost else {}
     hlo = compiled.as_text()
     # trip-count-weighted analysis: compiled.cost_analysis() counts scan
     # bodies ONCE (verified), under-reporting layer stacks by 24-100x.

@@ -10,6 +10,8 @@ import math
 
 import jax
 
+from ..runtime.sharding import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 (one v5e pod, 256 chips) or 2x16x16 (two pods over DCI).
@@ -27,12 +29,12 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512 (dryrun.py "
             f"sets this automatically)"
         )
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return make_mesh(shape, axes, devices=devs[:need])
 
 
 def make_debug_mesh(data: int = 2, model: int = 4):
     """Small mesh for multi-device CPU tests (8 fake devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 __all__ = ["make_production_mesh", "make_debug_mesh"]

@@ -35,7 +35,7 @@ from ..configs import get_config
 from ..data import SyntheticLM
 from ..models import model as M
 from ..models.config import reduced as reduce_cfg
-from ..runtime import chaos, guard, telemetry
+from ..runtime import chaos, compile_cache, guard, telemetry
 from ..runtime.events import get_logger
 from ..runtime.fault import StragglerMonitor, elastic_mesh
 from ..train import make_prefill_step, make_serve_step, prebuild_kron_ops
@@ -518,6 +518,7 @@ def main() -> None:
                     help="token id treated as EOS (default: none; requests "
                          "run to their per-request max-new)")
     args = ap.parse_args()
+    compile_cache.configure()
     if args.distributed and not args.kron_ffn:
         ap.error("--distributed requires --kron-ffn (it distributes the "
                  "batched Kron-FFN prefill)")
@@ -542,7 +543,7 @@ def main() -> None:
     dist_scope = (
         kron_distributed(mesh) if args.distributed else contextlib.nullcontext()
     )
-    with mesh, dist_scope:
+    with jax.set_mesh(mesh), dist_scope:
         if args.arrival_rate is not None:
             _continuous(args, cfg, mesh, log)
         else:

@@ -26,7 +26,7 @@ from ..configs import get_config
 from ..data import SyntheticLM
 from ..models.config import reduced as reduce_cfg
 from ..optim import OptConfig, ShampooConfig, state_memory_report
-from ..runtime import guard, telemetry
+from ..runtime import compile_cache, guard, telemetry
 from ..runtime.events import get_logger
 from ..runtime.fault import StragglerMonitor, elastic_mesh
 from ..runtime.sharding import param_shardings, token_sharding
@@ -71,6 +71,7 @@ def main() -> None:
                     help="Chrome-trace (Perfetto) export of the host-side "
                          "spans, written at exit")
     args = ap.parse_args()
+    compile_cache.configure()
     if args.numerics is not None:
         guard.set_numerics_policy(args.numerics)
     if args.telemetry or args.trace:
@@ -101,7 +102,7 @@ def main() -> None:
     mgr = CheckpointManager(args.ckpt_dir, keep=3, async_save=True) \
         if args.ckpt_dir else None
 
-    with mesh:
+    with jax.set_mesh(mesh):
         state = train_state_init(cfg, opt_cfg, jax.random.PRNGKey(0))
         p_shard = param_shardings(
             jax.eval_shape(lambda: state.params), mesh,

@@ -104,16 +104,13 @@ def moe_apply(cfg: ModelConfig, p: dict, x: jax.Array):
     from ..runtime.sharding import constrain
 
     e_tp = None  # expert axis role: "tp" when expert-parallel applies
-    try:
-        from ..runtime.sharding import ambient_mesh, _axes, _size
+    from ..runtime.sharding import ambient_mesh, _axes, _size
 
-        mesh = ambient_mesh()
-        if mesh is not None:
-            _, tp_name = _axes(mesh)
-            if mc.n_experts % _size(mesh, tp_name) == 0:
-                e_tp = "tp"
-    except Exception:
-        pass
+    mesh = ambient_mesh()
+    if mesh is not None:
+        _, tp_name = _axes(mesh)
+        if mc.n_experts % _size(mesh, tp_name) == 0:
+            e_tp = "tp"
 
     e = mc.n_experts
     # dispatch: (B, E*C, D) gather from token-major x

@@ -23,6 +23,7 @@ import jax
 
 from . import telemetry
 from .events import get_logger
+from .sharding import make_mesh
 
 
 @dataclass
@@ -112,7 +113,7 @@ def elastic_mesh(
     while model * 2 <= want_model and n_devices % (model * 2) == 0:
         model *= 2
     data = n_devices // model
-    return jax.make_mesh((data, model), axis_names, devices=devices)
+    return make_mesh((data, model), axis_names, devices=devices)
 
 
 __all__ = ["StragglerMonitor", "elastic_mesh"]

@@ -102,5 +102,29 @@ def collective_bytes(hlo_text: str) -> int:
     return collective_stats(hlo_text).total_bytes
 
 
+# Source locations in compiled HLO text: tables of the Python call stack
+# at trace time (whose line numbers differ between two call sites) and a
+# stack-frame id or file/line attributes on instructions.
+_LOCATION_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+_LOCATION_ATTR = re.compile(
+    r' (?:stack_frame_id=\d+|source_file="[^"]*"|source_(?:end_)?(?:line|column)=\d+)'
+)
+
+
+def strip_source_locations(hlo_text: str) -> str:
+    """``compiled.as_text()`` without its source locations: two compiles of
+    one program traced from different lines compare equal, while op names
+    (where named scopes land) and everything else are kept."""
+    out, in_table = [], False
+    for line in hlo_text.splitlines():
+        if line in _LOCATION_TABLES:
+            in_table = True
+        elif in_table and not line.strip():
+            in_table = False
+        elif not in_table:
+            out.append(_LOCATION_ATTR.sub("", line))
+    return "\n".join(out)
+
+
 __all__ = ["collective_stats", "collective_bytes", "shape_bytes", "CollectiveStats",
-           "COLLECTIVE_OPS"]
+           "COLLECTIVE_OPS", "strip_source_locations"]

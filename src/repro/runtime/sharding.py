@@ -17,7 +17,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def _axes(mesh: Mesh) -> tuple[tuple[str, ...], str]:
@@ -141,17 +141,21 @@ def batch_spec(mesh: Mesh) -> P:
     return P(ax)
 
 
-def ambient_mesh() -> Mesh | None:
-    """The mesh installed by ``with mesh:`` around the current trace, if any."""
-    try:
-        from jax._src.mesh import thread_resources
+def make_mesh(shape, axis_names, *, devices=None) -> Mesh:
+    """A device mesh whose axes are all ``AxisType.Auto``: shardings are
+    propagated by XLA, as this code base's constraints and shard_map bodies
+    assume (``jax.make_mesh`` now defaults to explicit axes)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axis_names), devices=devices,
+        axis_types=(AxisType.Auto,) * len(tuple(shape)),
+    )
 
-        m = thread_resources.env.physical_mesh
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
-    return None
+
+def ambient_mesh():
+    """The mesh set by ``jax.set_mesh`` around the current trace, if any (an
+    ``AbstractMesh`` inside ``jit``: axis names and sizes, no devices)."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def constrain_like_params(tree: Any) -> Any:

@@ -39,6 +39,7 @@ from repro.core.distributed import (  # noqa: E402
 )
 from repro.runtime import chaos, guard  # noqa: E402
 from repro.runtime.hlo_analysis import collective_stats  # noqa: E402
+from repro.runtime.sharding import make_mesh  # noqa: E402
 
 G_M, G_K = 2, 4
 B, M, PS, QS = 8, 8, (4, 4, 4), (4, 4, 4)
@@ -57,7 +58,7 @@ def _mk(seed=0):
 def main() -> None:
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 devices, got {len(devs)}"
-    mesh = jax.make_mesh((G_M, G_K), ("data", "model"))
+    mesh = make_mesh((G_M, G_K), ("data", "model"))
     x, fs = _mk(seed=3)
     xs = sharded_input_batched(x, mesh)
     rounds = plan_rounds(

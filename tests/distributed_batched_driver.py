@@ -33,6 +33,7 @@ from repro.core.distributed import (  # noqa: E402
     sharded_input_batched,
 )
 from repro.runtime.hlo_analysis import collective_stats  # noqa: E402
+from repro.runtime.sharding import make_mesh  # noqa: E402
 
 G_M, G_K = 2, 4
 
@@ -63,7 +64,7 @@ def _looped(x, fs, mesh, *, per_sample):
 def main() -> None:
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 devices, got {len(devs)}"
-    mesh = jax.make_mesh((G_M, G_K), ("data", "model"))
+    mesh = make_mesh((G_M, G_K), ("data", "model"))
 
     cases = [
         (8, 8, (4, 4, 4), (4, 4, 4)),     # rounds [2, 1] on G_K=4

@@ -25,6 +25,7 @@ from repro.core.distributed import (  # noqa: E402
 
 
 from repro.runtime.hlo_analysis import collective_bytes as _hlo_bytes  # noqa: E402
+from repro.runtime.sharding import make_mesh  # noqa: E402
 
 
 def collective_bytes(fn, *args) -> int:
@@ -35,7 +36,7 @@ def collective_bytes(fn, *args) -> int:
 def main() -> None:
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 devices, got {len(devs)}"
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
 
     # --- correctness: batched relocation == naive oracle -------------------
     cases = [
@@ -108,7 +109,7 @@ def main() -> None:
           f"(analytic elems/dev {analytic_batched} vs {analytic_periter})")
 
     # --- G_M axis is communication-free (rows embarrassingly parallel) ------
-    mesh_dp = jax.make_mesh((8, 1), ("data", "model"))
+    mesh_dp = make_mesh((8, 1), ("data", "model"))
     xs_dp = sharded_input(jnp.ones((8, 256)), mesh_dp)
     cb_dp = collective_bytes(lambda x_, fs: kron_matmul_distributed(x_, fs, mesh_dp),
                              xs_dp, factors)

@@ -47,6 +47,7 @@ from repro.core.engine import KronOp  # noqa: E402
 from repro.kernels.emit import effective_slabs  # noqa: E402
 from repro.runtime import telemetry  # noqa: E402
 from repro.runtime.hlo_analysis import collective_stats  # noqa: E402
+from repro.runtime.sharding import make_mesh  # noqa: E402
 
 G_M, G_K = 2, 4
 
@@ -58,7 +59,7 @@ def _bitwise(a, b) -> bool:
 def main() -> None:
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 devices, got {len(devs)}"
-    mesh = jax.make_mesh((G_M, G_K), ("data", "model"))
+    mesh = make_mesh((G_M, G_K), ("data", "model"))
 
     M, PS, QS = 16, (4, 4, 4), (4, 4, 4)
     K = math.prod(PS)

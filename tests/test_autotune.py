@@ -217,3 +217,36 @@ def test_measure_best_ranks_by_wallclock():
         fn_of_cfg, [TileConfig(1, 1, 1), TileConfig(8, 8, 8)], warmup=1, iters=2
     )
     assert best.t_m == 8 and dt > 0
+
+
+def test_measure_best_records_dropped_candidates():
+    from repro.runtime import guard
+
+    guard.reset_health()
+
+    def fn_of(cfg):
+        if cfg == "bad":
+            raise ValueError("no such tiling")
+        return lambda: jnp.ones(4)
+
+    best, _ = measure_best(fn_of, ["bad", "good"], warmup=0, iters=1)
+    assert best == "good"
+    events = guard.health_report()["events"]
+    assert events["measure_dropped"] == 1
+    assert events["measure_dropped:ValueError"] == 1
+    guard.reset_health()
+
+
+def test_measure_best_propagates_bugs():
+    def fn_of(cfg):
+        raise TypeError("a bug, not a tiling the chip refuses")
+
+    with pytest.raises(TypeError):
+        measure_best(fn_of, ["a"], warmup=0, iters=1)
+
+
+@pytest.mark.parametrize("m,s", [(1526, 256), (16, 4096), (20, 1)])
+def test_candidate_tiles_are_legal_tpu_blocks(m, s):
+    for c in candidate_tiles(m, s, 16, 16):
+        assert c.t_m == m or c.t_m % 8 == 0
+        assert c.t_s == s or c.t_s % 128 == 0

@@ -23,10 +23,11 @@ import repro.launch.mesh as mesh_mod
 
 def small_mesh(*, multi_pod=False):
     assert not multi_pod
-    return jax.make_mesh((2, 4), ("data", "model"))
+    return make_mesh((2, 4), ("data", "model"))
 
 mesh_mod.make_production_mesh = small_mesh
 from repro.launch import dryrun
+from repro.runtime.sharding import make_mesh
 rec = dryrun.run_cell("mamba2_130m", "decode_32k", False, None)
 assert rec["chips"] == 8
 assert rec["per_device"]["hlo_flops"] > 0

@@ -263,7 +263,7 @@ def test_lower_carries_single_stage_q_tile_for_huge_q():
     huge-Q factor whose full-Q growth would fail the chain template's VMEM
     check must lower to an emittable instruction (the kron_sliced kernel's
     t_q semantics, now expressed as a length-1 t_qs)."""
-    m, ps, qs = 64, (2, 2), (4096, 4096)
+    m, ps, qs = 64, (2, 2), (32768, 32768)
     plan = make_plan(KronProblem(m, ps, qs), enable_fusion=False,
                      enable_prekron=False)
     prog = lower(plan, ps, qs)
@@ -335,3 +335,87 @@ def test_run_program_validates_factor_count():
     )
     with pytest.raises(ValueError):
         emit.run_program(jnp.zeros((2, 4)), (jnp.zeros((4, 4)),) * 2, prog)
+
+
+# ---------------------------------------------------------------------------
+# Compiled-kernel legality: block shapes Mosaic accepts, padded VMEM, routing
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "m,k,ps,t_m,t_k,t_qs,itemsize,legal",
+    [
+        (16, 512, (8, 8, 8), 16, 512, None, 4, True),  # full extents
+        (16, 512, (8,), 4, 512, None, 4, False),  # rows: not a sublane multiple
+        (32, 512, (8,), 16, 512, None, 2, True),  # bf16 sublane tile is 16
+        (32, 512, (8,), 8, 512, None, 2, False),
+        (16, 4096, (16,), 16, 16 * 128, None, 4, True),  # 128 slices per block
+        (16, 4096, (16,), 16, 16 * 64, None, 4, False),  # 64 slices per block
+        (16, 512, (8,), 16, 512, (4,), 4, False),  # Q-tiled output block
+    ],
+)
+def test_tpu_block_error_legality(m, k, ps, t_m, t_k, t_qs, itemsize, legal):
+    why = emit.tpu_block_error(1, m, k, ps, ps, t_m, t_k, t_qs, itemsize)
+    assert (why is None) == legal, why
+
+
+def test_legal_tiles_are_legal_and_fit_padded_vmem():
+    """qwen3-4b kron_ffn up-projection factors at batch 4 x seq 512."""
+    ps, qs = (40, 64), (76, 128)
+    for grad in (False, True):
+        t_b, t_m, t_k = emit.legal_tiles(
+            "fwd", 1, 2048, 2560, ps, qs, t_b=1, t_m=32, itemsize=4, grad=grad
+        )
+        assert emit.tpu_block_error(1, 2048, 2560, ps, qs, t_m, t_k, None, 4) is None
+        need = emit.chain_vmem_bytes(
+            t_b, t_m, t_k, ps, qs, direction="fwd", flat=t_k == 2560,
+            in_bytes=4, out_bytes=4, grad=grad,
+        )
+        assert need <= emit.VMEM_BUDGET_ELEMS * 4
+    assert emit.legal_tiles(
+        "fwd", 1, 2048, 2560, ps, qs, t_b=1, t_m=32, itemsize=4,
+        budget_bytes=1 << 16,
+    ) is None
+
+
+def test_vmem_model_counts_lane_padding():
+    """A (t_m, 256, 256, 1)-shaped block pads its trailing 1 to 128 lanes."""
+    assert emit._tiled_bytes((8, 256, 1), 4) == 8 * 256 * 128 * 4
+    assert emit._tiled_bytes((4, 100), 2) == 16 * 128 * 2
+
+
+def test_no_legal_tiling_routes_stage_to_xla_in_describe():
+    """Table 4 row 22: M=1526 has no multiple-of-8 divisor, and a full-M
+    block of the (16,16) prekron stage needs ~119 MiB of VMEM, over the
+    budget — the compiled emitter routes it to XLA, decided from shapes and
+    shown by ``describe()``."""
+    instr = emit.StageInstr(emit.PREKRON, (4, 4), (4, 4), (0, 1), t_m=1526)
+    assert emit.stage_tiles(instr, (1526, 4096), jnp.float32) is None
+    assert emit.stage_tiles(instr, (1526, 4096), jnp.float32, grad=True) is None
+    op = KronOp((4,) * 6, (4,) * 6, m=1526, backend="pallas")
+    assert "xla" in op.describe().split(":: exec[")[1]
+    assert all(ex != "xla" for _, ex, _ in
+               KronOp((8,) * 3, (8,) * 3, m=16, backend="pallas").stage_executors())
+
+
+@pytest.mark.parametrize(
+    "s,p,q,lanes", [(4, 8, 8, 16), (1, 40, 76, 32), (6, 4, 16, 128)]
+)
+def test_slice_batched_step_matches_lane_merged_step(s, p, q, lanes):
+    """Compiled, a factor step whose lanes are not a multiple of 128 runs as
+    a GEMM batched over its slices; interpreted, every step folds the slices
+    into the lanes.  Both forms give the same step, transposed step and
+    factor gradient."""
+    kw, kf, kg = jax.random.split(jax.random.PRNGKey(0), 3)
+    w = jax.random.normal(kw, (s * p, lanes), jnp.float32)
+    f = jax.random.normal(kf, (p, q), jnp.float32)
+    g = jax.random.normal(kg, (q * s, lanes), jnp.float32)
+    tol = dict(rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(
+        emit._step(w, f, jnp.float32, False),
+        emit._step(w, f, jnp.float32, True), **tol,
+    )
+    df_b, out_b = emit._step_t(g, f, jnp.float32, False, u=w)
+    df_m, out_m = emit._step_t(g, f, jnp.float32, True, u=w)
+    np.testing.assert_allclose(out_b, out_m, **tol)
+    np.testing.assert_allclose(df_b, df_m, **tol)

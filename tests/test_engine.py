@@ -26,6 +26,7 @@ from repro.core.autotune import make_batched_plan, make_plan
 from repro.core.engine import kron_op_for
 from repro.core.kron import KronProblem, kron_matrix
 from repro.core.layers import KronLinear, KronLinearSpec, kron_linear_materialize
+from repro.runtime.sharding import make_mesh
 
 
 def _mk(seed, m, ps, qs, batch=None):
@@ -128,13 +129,13 @@ def test_with_batch_and_with_mesh_derivations():
     opb = op.with_batch(8, shared_factors=False)
     assert (opb.batch, opb.shared_factors) == (8, False)
     assert (opb.ps, opb.qs) == (op.ps, op.qs)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     opd = op.with_mesh(mesh)
     assert opd.mesh is mesh and opd.rounds is not None
     assert opd.cost(m=8).rounds == len(opd.rounds)
     # infeasible round schedule fails AT CONSTRUCTION (fail fast), not at call
     if jax.device_count() >= 2:
-        bad = jax.make_mesh((1, jax.device_count()), ("data", "model"))
+        bad = make_mesh((1, jax.device_count()), ("data", "model"))
         ps = (3, 3)  # prod(Q)=9 never divisible by an even G_K
         if jax.device_count() % 2 == 0:
             with pytest.raises(ValueError):
@@ -144,7 +145,7 @@ def test_with_batch_and_with_mesh_derivations():
 def test_mesh_op_on_trivial_mesh_matches_local():
     """The mesh spine is the same math: a 1x1 mesh reproduces the local op
     bit-for-bit shapes/numerics (collectives degenerate away)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     x, fs = _mk(2, 8, (4, 4), (4, 4))
     op = KronOp((4, 4), (4, 4), mesh=mesh)
     got = op(x, fs)
@@ -182,7 +183,7 @@ def test_legacy_shims_warn_once_and_match_op_exactly():
 
 
 def test_distributed_shims_warn_once():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     from repro.core import distributed
 
     x, fs = _mk(5, 8, (4, 4), (4, 4))

@@ -14,6 +14,7 @@ from repro.models.config import reduced
 from repro.optim import OptConfig
 from repro.runtime.fault import StragglerMonitor, elastic_mesh
 from repro.train import make_train_step, train_state_init
+from repro.runtime.sharding import make_mesh
 
 
 def _tiny():
@@ -85,7 +86,7 @@ def test_cross_mesh_restore(tmp_path):
     mgr = CheckpointManager(str(tmp_path), keep=1)
     w = jnp.arange(16.0).reshape(4, 4)
     mgr.save(1, {"w": w})
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     target = jax.ShapeDtypeStruct((4, 4), jnp.float32)
     target = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(

@@ -238,12 +238,22 @@ def test_fused_kernel_q_tiling_lifts_vmem_restriction():
     """Problems where t_m*t_k*growth exceeds the budget become legal by
     tiling Q (acceptance criterion for the Q-tile grid axis)."""
     x, fls = _mk_chain(21, 8, (2, 2), (16, 16))
-    # full Q: growth = 256/4 = 64 -> 8*4*64 = 2048 elems > 1024 budget
+    # The padded VMEM model: full Q needs ~2.7 MB, Q-tiles of 4 ~0.24 MB.
+    from repro.kernels import emit
+
+    def need(t_qs):
+        return emit.chain_vmem_bytes(
+            1, 8, 4, (2, 2), t_qs, direction="fwd", flat=False,
+            in_bytes=4, out_bytes=4,
+        )
+
+    budget = 1 << 17  # elements, i.e. 512 KiB
+    assert need((4, 4)) <= budget * 4 < need((16, 16))
     with pytest.raises(ValueError):
         fused_kron_pallas(x, *fls, t_m=8, t_k=4, interpret=True,
-                          vmem_budget_elems=1024)
+                          vmem_budget_elems=budget)
     got = fused_kron_pallas(x, *fls, t_m=8, t_k=4, t_qs=(4, 4), interpret=True,
-                            vmem_budget_elems=1024)
+                            vmem_budget_elems=budget)
     np.testing.assert_allclose(
         got, fused_kron_ref(x, list(reversed(fls))), rtol=1e-5, atol=1e-5
     )

@@ -27,6 +27,7 @@ from repro.optim import shampoo as sh
 from repro.optim.adamw import OptConfig, opt_init, opt_update
 from repro.optim.shampoo import ShampooConfig
 from repro.runtime import chaos, guard, telemetry
+from repro.runtime.hlo_analysis import strip_source_locations
 
 
 @pytest.fixture(autouse=True)
@@ -400,7 +401,7 @@ def test_telemetry_off_adds_zero_hlo_to_optimizer_step():
     on = compiled_text()
     telemetry.reset()
     off_after = compiled_text()
-    assert off_before == off_after
+    assert strip_source_locations(off_before) == strip_source_locations(off_after)
     assert "kronscope" not in off_after
     del on  # annotation side of the pin is covered in test_telemetry
 

@@ -7,12 +7,13 @@ from jax.sharding import PartitionSpec as P
 from repro.runtime.hlo_analysis import collective_stats, shape_bytes
 from repro.runtime.hlo_cost import analyze
 from repro.runtime.sharding import cache_spec, param_spec
+from repro.runtime.sharding import make_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh():
     # single-device mesh still exercises the rule logic (sizes are 1)
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_param_spec_roles(mesh):
@@ -29,13 +30,13 @@ def test_param_spec_roles(mesh):
 
 
 def test_param_spec_moe_expert_vs_tp(mesh):
-    big = jax.make_mesh((1, 1), ("data", "model"))
+    big = make_mesh((1, 1), ("data", "model"))
     # E divisible by tp (1) -> expert parallel
     assert param_spec("ffn/ew1", (4, 8, 16), big) == P("model", "data", None)
 
 
 def test_param_spec_divisibility_fallback():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # dims of size 7 can't shard over axes of size 1? size-1 axes divide
     # everything; rules still apply. Use the path where dim % size != 0 by
     # constructing spec directly via _fit semantics: with 1-device axes all
@@ -99,8 +100,6 @@ def test_hlo_cost_trip_weighting():
         "c = analyze(txt)\n"
         "assert c.dot_flops == 7 * 2 * 32**3, c.dot_flops\n"
         "raw = lowered.compile().cost_analysis()\n"
-        "if isinstance(raw, (list, tuple)):\n"
-        "    raw = raw[0]  # jax < 0.5 wraps the dict in a list\n"
         "assert raw['flops'] < 2 * 2 * 32**3, raw['flops']  # ~1 iter, not 7\n"
         "print('TRIP-OK')\n"
     )
