@@ -427,9 +427,7 @@ def _dist_body(
     for k, r in enumerate(rounds):
         fs = tuple(factors_rev[i : i + r])
         qprod = math.prod(qs[i : i + r])
-        with telemetry.span(
-            "round", k=k, n_factors=r, n_slabs=n, batched=t_b is not None
-        ):
+        with telemetry.scope("round"):
             slabs = _slab_round(
                 slabs, fs, qprod, g_k, model_axis, backend, t_b, k,
                 record=record,
@@ -495,10 +493,7 @@ def _dist_body_bwd(
     g_slabs = emit.split_slabs(g, n, axis=-2)
     for k in reversed(range(len(rounds))):
         i0, fs, qprod = meta[k]
-        with telemetry.span(
-            "round_bwd", k=k, n_factors=len(fs), n_slabs=n,
-            batched=t_b is not None,
-        ):
+        with telemetry.scope("round_bwd"):
             def _undo(gs):
                 if g_k > 1:
                     return _relocate_slab_t(gs, qprod, g_k, model_axis, n)
@@ -560,7 +555,8 @@ def _rounds_fn(
 
     def bwd(res, g):
         x_loc, factors_rev = res
-        return _dist_body_bwd(x_loc, factors_rev, g, **cfg)
+        with telemetry.scope("op_bwd"):
+            return _dist_body_bwd(x_loc, factors_rev, g, **cfg)
 
     rounds.defvjp(fwd, bwd)
     return rounds

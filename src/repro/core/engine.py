@@ -590,6 +590,10 @@ def _kron_fn(plan: KronPlan | None, backend: str, pctx: _PlanCtx, batched: bool)
         return fwd_only(x, factors), (x, factors, f_pert)
 
     def kron_bwd(res, g):
+        with telemetry.scope("op_bwd"):
+            return _bwd(res, g)
+
+    def _bwd(res, g):
         x, factors, f_pert = res
         if isinstance(g, jax.custom_derivatives.SymbolicZero):
             return jnp.zeros_like(x), tuple(jnp.zeros_like(f) for f in factors)
@@ -1371,6 +1375,12 @@ class KronOp:
                     )
 
     def __call__(self, x: jax.Array, factors: Sequence[jax.Array]) -> jax.Array:
+        # One scope for the whole forward call, local or mesh: the plan's
+        # relayouts around the program, and its rounds, issue under it.
+        with telemetry.scope("op"):
+            return self._call(x, factors)
+
+    def _call(self, x: jax.Array, factors: Sequence[jax.Array]) -> jax.Array:
         factors = tuple(factors)
         self._check_factors(factors)
         if self.batch is None:

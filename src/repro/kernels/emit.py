@@ -1346,9 +1346,7 @@ def run_stage(
     hold the stage in VMEM.
     """
     chaos.maybe_fail("stage_execute")
-    # One truthiness check when telemetry is off (span() returns a shared
-    # no-op): no named_scope enters the trace, compiled HLO is unchanged.
-    with telemetry.span("stage", kind=instr.kind, direction=instr.direction):
+    with telemetry.scope("stage"):
         fs = tuple(stage_factors)
         direction, fs, t_qs = _effective(instr, fs)
         b = resolve_backend(backend)
@@ -1406,7 +1404,7 @@ def run_stage_grad(
     own tiles cannot hold the stage's live set in VMEM.
     """
     chaos.maybe_fail("stage_execute")
-    with telemetry.span("stage_grad", kind=instr.kind):
+    with telemetry.scope("stage_grad"):
         fs = tuple(stage_factors)
         b = resolve_backend(backend)
         if b == "xla":
@@ -1468,7 +1466,7 @@ def run_program(
             f"program expects {prog.n_factors} factors, got {len(factors)}"
         )
     rev = tuple(reversed(factors))
-    with telemetry.span("program", stages=len(prog.instrs)):
+    with telemetry.scope("program"):
         y = x
         for instr in prog.instrs:
             y = run_stage(

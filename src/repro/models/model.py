@@ -24,6 +24,7 @@ from . import attention as attn
 from . import ffn as ffn_mod
 from . import moe as moe_mod
 from . import ssm
+from ..runtime import telemetry
 from ..runtime.sharding import constrain
 from .common import embed_init, rms_norm
 from .config import LayerSpec, ModelConfig
@@ -85,24 +86,25 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
 
 
 def _layer_forward(cfg, spec: LayerSpec, p, x, positions):
-    """Full-sequence layer.  Returns (x, aux, kv_for_cache|state|None)."""
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    cache_out = None
-    if spec.kind == "attn":
-        mix, kv = attn.attn_forward(cfg, p["mixer"], h, positions, return_kv=True)
-        cache_out = kv
-    else:
-        mix, state = ssm.mamba_forward(cfg, p["mixer"], h, return_state=True)
-        cache_out = state
-    x = x + mix
+    """Full-sequence layer.  Returns (x, aux, kv_for_cache|state|None).
+    Each half (norm, mixer or FFN, residual add) is a ``model.*`` scope."""
+    with telemetry.scope("model.attn" if spec.kind == "attn" else "model.ssm"):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        if spec.kind == "attn":
+            mix, cache_out = attn.attn_forward(cfg, p["mixer"], h, positions,
+                                               return_kv=True)
+        else:
+            mix, cache_out = ssm.mamba_forward(cfg, p["mixer"], h, return_state=True)
+        x = x + mix
     aux = jnp.zeros((), jnp.float32)
-    if spec.moe:
-        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        y, aux = moe_mod.moe_apply(cfg, p["ffn"], h2)
-        x = x + y
-    elif cfg.d_ff:
-        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        x = x + ffn_mod.ffn_apply(cfg, p["ffn"], h2)
+    with telemetry.scope("model.ffn"):
+        if spec.moe:
+            h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+            y, aux = moe_mod.moe_apply(cfg, p["ffn"], h2)
+            x = x + y
+        elif cfg.d_ff:
+            h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+            x = x + ffn_mod.ffn_apply(cfg, p["ffn"], h2)
     return x, aux, cache_out
 
 
@@ -138,16 +140,17 @@ def _embed(cfg, params, tokens, embeds):
 
 
 def _head(cfg, params, x):
-    h = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = h @ params["embed"].T
-    else:
-        logits = h @ params["lm_head"]
-    # mask padded vocab rows so they never win the softmax
-    if cfg.padded_vocab != cfg.vocab:
-        pad_mask = jnp.arange(cfg.padded_vocab) >= cfg.vocab
-        logits = jnp.where(pad_mask, -1e9, logits.astype(jnp.float32))
-    return constrain(logits.astype(jnp.float32), "batch", None, "tp")
+    with telemetry.scope("model.head"):
+        h = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = h @ params["embed"].T
+        else:
+            logits = h @ params["lm_head"]
+        # mask padded vocab rows so they never win the softmax
+        if cfg.padded_vocab != cfg.vocab:
+            pad_mask = jnp.arange(cfg.padded_vocab) >= cfg.vocab
+            logits = jnp.where(pad_mask, -1e9, logits.astype(jnp.float32))
+        return constrain(logits.astype(jnp.float32), "batch", None, "tp")
 
 
 # ---------------------------------------------------------------------------
@@ -205,7 +208,10 @@ def forward(
 
     if cfg.remat:
         body = jax.checkpoint(body)
-    (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), params["stack"])
+    # the scan's own work (each layer's weights sliced from the stack, its
+    # gradients stacked back) is the stack's; the layers name their halves
+    with telemetry.scope("model.stack"):
+        (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), params["stack"])
     return _head(cfg, params, x), aux_total
 
 

@@ -423,7 +423,7 @@ def shampoo_update(
     # Statistics + amortized root refresh for the eligible leaves.
     new_kron: dict = {}
     n_bad = jnp.zeros((), jnp.int32)
-    with telemetry.span("optim.root_refresh", every=cfg.precond_every):
+    with telemetry.scope("optim.root_refresh"):
         for i, path in enumerate(paths):
             if path not in kron:
                 continue
@@ -469,9 +469,7 @@ def shampoo_update(
     # Shape-grouped batched preconditioning of the Adam direction + graft.
     u_final = list(u_adam)
     if new_kron:
-        with telemetry.span(
-            "optim.precondition", groups=len(_groups_of_kron(new_kron))
-        ):
+        with telemetry.scope("optim.precondition"):
             idx = {path: i for i, path in enumerate(paths)}
             shapes = {
                 path: (e["ok"].shape[0], e["l"].shape[-1], e["r"].shape[-1])

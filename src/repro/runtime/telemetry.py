@@ -2,20 +2,30 @@
 
 One telemetry layer for the whole Kron-Matmul execution path — plan →
 emit → execute → collectives — so every later perf PR starts from measured
-evidence instead of scattered prints.  Three pieces:
+evidence instead of scattered prints.  Four pieces:
 
-* **Spans** — ``span("round", k=2)`` times a region host-side
-  (``perf_counter``) and, while telemetry is active, wraps it in
-  ``jax.named_scope`` + ``jax.profiler.TraceAnnotation`` so the region is
-  attributable in compiled HLO metadata and XLA device profiles.  Spans
-  nest; each completed span also feeds the ``span.<name>`` histogram.
+* **Scopes** — ``scope("stage")`` is ``jax.named_scope("kronscope.stage")``,
+  always on.  It names the program layer that issued each instruction in
+  the compiled HLO's ``op_name`` metadata, so a device trace can be read
+  by layer (``bench/scopes.py``).  It changes metadata only: the compiled
+  instructions are the same with or without it, and it records nothing on
+  the host.  Sites call it as ``telemetry.scope(...)`` so a test can
+  replace it.
+
+* **Spans** — ``span("plan", m=16)`` times a host-side region
+  (``perf_counter``) while telemetry is active, and wraps it in a
+  ``jax.profiler.TraceAnnotation`` (prefix ``kronscope.``) so a profiler
+  trace shows it on the same clock as the device.  Spans nest; each
+  completed span also feeds the ``span.<name>`` histogram.  A span inside
+  jitted code would time tracing, not execution: those regions are scopes.
 
 * **Metrics** — a registry of counters (``counter_inc``), gauges
   (``gauge_set``) and histograms (``observe``, with p50/p95/p99 via
   ``percentiles``), fed by the existing subsystems: plan-cache hit/miss
   (autotune), ladder rung transitions and chaos injections (guard/chaos),
   straggler flags (runtime.fault), per-round ``comm_elems_per_device``
-  (distributed), decode tokens/s and step latency (the launchers).
+  (distributed), compiles (``compile_cache``), decode tokens/s and step
+  latency (the launchers).
 
 * **Export** — every span and event streams to a JSONL sink
   (``repro.runtime.events.EventSink``) and completed spans export as a
@@ -23,13 +33,10 @@ evidence instead of scattered prints.  Three pieces:
   ``write_chrome_trace``; ``--telemetry out.jsonl --trace out.trace.json``
   on the launchers and ``benchmarks/run.py`` wires both.
 
-Disabled (the default) the layer is inert: every instrumentation site costs
-one module-global truthiness check, NO ``named_scope``/``TraceAnnotation``
-enters traced code, and compiled HLO is bitwise-identical to a build without
-telemetry — pinned by ``tests/test_telemetry.py`` exactly like the guard
-layer's zero-overhead pin (EXPERIMENTS.md §Robustness).  Like guard health,
-activation is trace-time state: functions compiled before ``configure()``
-keep their un-annotated executables.
+Disabled (the default) spans and metrics are inert: every site costs one
+module-global truthiness check.  Scopes do not depend on it, so a program
+compiles to one executable whether telemetry is on or off — pinned by
+``tests/test_telemetry.py``.
 """
 from __future__ import annotations
 
@@ -52,10 +59,9 @@ DRIFT_THRESHOLD = 2.0  # default measured/predicted per-stage drift ratio flag
 class _Telemetry:
     """The live telemetry state; exists only while telemetry is active."""
 
-    def __init__(self, jsonl=None, trace=None, annotate: bool = True):
+    def __init__(self, jsonl=None, trace=None):
         self.t0 = time.perf_counter()
         self.started_at = time.strftime("%Y-%m-%dT%H:%M:%S")
-        self.annotate = bool(annotate)
         self.sink = EventSink(jsonl) if jsonl else None
         self.trace_path = str(trace) if trace else None
         self.lock = threading.RLock()
@@ -83,17 +89,15 @@ def active() -> bool:
     return _STATE is not None
 
 
-def configure(jsonl=None, trace=None, *, annotate: bool = True) -> None:
+def configure(jsonl=None, trace=None) -> None:
     """Activate telemetry for the process.
 
     ``jsonl``: path for the JSONL event stream (None = in-memory only).
     ``trace``: path ``shutdown()`` writes the Chrome trace to.
-    ``annotate``: wrap spans in ``jax.named_scope``/``TraceAnnotation``
-    (disable to keep compiled HLO pristine while still timing host-side).
     Reconfiguring replaces the previous state (its sink is closed).
     """
     global _STATE
-    old, _STATE = _STATE, _Telemetry(jsonl, trace, annotate=annotate)
+    old, _STATE = _STATE, _Telemetry(jsonl, trace)
     if old is not None and old.sink is not None:
         old.sink.close()
 
@@ -127,8 +131,18 @@ def shutdown() -> dict | None:
 
 
 # ---------------------------------------------------------------------------
-# Spans
+# Scopes (compiled programs) and spans (the host)
 # ---------------------------------------------------------------------------
+
+
+def scope(name: str):
+    """``jax.named_scope("kronscope.<name>")``: names the program layer of
+    every instruction traced inside it, in the compiled HLO's ``op_name``
+    metadata.  Always on and independent of ``active()``; it adds metadata
+    only, never an instruction, and records no host time."""
+    import jax
+
+    return jax.named_scope(f"kronscope.{name}")
 
 
 class _NullSpan:
@@ -147,7 +161,7 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("state", "name", "attrs", "depth", "t_start", "_ns", "_ta")
+    __slots__ = ("state", "name", "attrs", "depth", "t_start", "_ta")
 
     def __init__(self, state: _Telemetry, name: str, attrs: dict):
         self.state = state
@@ -159,24 +173,17 @@ class _Span:
         stack = st.stack()
         self.depth = len(stack)
         stack.append(self.name)
-        if st.annotate:
-            import jax
+        import jax
 
-            self._ns = jax.named_scope(f"kronscope.{self.name}")
-            self._ns.__enter__()
-            self._ta = jax.profiler.TraceAnnotation(f"kronscope.{self.name}")
-            self._ta.__enter__()
-        else:
-            self._ns = self._ta = None
+        self._ta = jax.profiler.TraceAnnotation(f"kronscope.{self.name}")
+        self._ta.__enter__()
         self.t_start = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self.t_start
         st = self.state
-        if self._ta is not None:
-            self._ta.__exit__(*exc)
-            self._ns.__exit__(*exc)
+        self._ta.__exit__(*exc)
         stack = st.stack()
         if stack and stack[-1] == self.name:
             stack.pop()
@@ -201,12 +208,14 @@ class _Span:
 
 
 def span(name: str, **attrs):
-    """Context manager timing a region; a shared no-op when inactive.
+    """Context manager timing a host-side region; a shared no-op when
+    inactive.
 
     Active: records host wall time, nests (depth tracked per thread), wraps
-    the region in ``jax.named_scope`` + ``jax.profiler.TraceAnnotation``
-    (prefix ``kronscope.``), streams to the JSONL sink, and feeds the
-    ``span.<name>`` histogram.
+    the region in ``jax.profiler.TraceAnnotation`` (prefix ``kronscope.``),
+    streams to the JSONL sink, and feeds the ``span.<name>`` histogram.
+    Regions of jitted code take ``scope()`` instead: a span there would time
+    their tracing.
     """
     st = _STATE
     if st is None:
@@ -471,6 +480,7 @@ __all__ = [
     "disable",
     "reset",
     "shutdown",
+    "scope",
     "span",
     "record_span",
     "event",

@@ -20,6 +20,7 @@ from ..models.config import ModelConfig
 from ..optim.adamw import OptConfig
 from ..optim.shampoo import ShampooConfig, opt_for
 from ..optim import shampoo as _shampoo
+from ..runtime import telemetry
 from ..runtime.sharding import constrain_like_params
 
 
@@ -132,9 +133,10 @@ def loss_fn(
 ):
     logits, aux = M.forward(cfg, params, tokens, embeds)
     n_fe = cfg.n_frontend_tokens if embeds is not None else 0
-    logits = logits[:, n_fe:, :]
-    ll = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(ll, labels[..., None], axis=-1).mean()
+    with telemetry.scope("model.head"):
+        logits = logits[:, n_fe:, :]
+        ll = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(ll, labels[..., None], axis=-1).mean()
     return nll + aux_weight * aux, {"nll": nll, "aux": aux}
 
 
@@ -207,9 +209,10 @@ def make_train_step(
             loss = loss / microbatches
             parts = {"nll": loss, "aux": jnp.zeros((), jnp.float32)}
 
-        new_params, new_opt, opt_metrics = update_fn(
-            grads, state.opt, params, opt_cfg
-        )
+        with telemetry.scope("optim.update"):
+            new_params, new_opt, opt_metrics = update_fn(
+                grads, state.opt, params, opt_cfg
+            )
         metrics = {"loss": loss, **parts, **opt_metrics}
         return TrainState(new_params, new_opt, state.step + 1), metrics
 

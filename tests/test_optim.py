@@ -371,15 +371,28 @@ def test_numerics_policy_warn_and_raise():
 # ---------------------------------------------------------------------------
 
 
+def _shampoo_text(params, grads, st, cfg):
+    return (
+        jax.jit(lambda g, s: sh.shampoo_update(g, s, params, cfg))
+        .lower(grads, st)
+        .compile()
+        .as_text()
+    )
+
+
 def test_spans_and_histograms_when_active():
+    """The root refresh and the preconditioning are scopes of the compiled
+    update (a span inside jit would time its trace): they are in its HLO,
+    and no ``span.optim.*`` histogram is recorded."""
     params, grads = _params(), _grads(_params())
     cfg = ShampooConfig()
     st = sh.shampoo_init(params, cfg)
     telemetry.configure()
-    sh.shampoo_update(grads, st, params, cfg)
+    txt = _shampoo_text(params, grads, st, cfg)
     snap = telemetry.snapshot()
-    assert "span.optim.root_refresh" in snap["histograms"]
-    assert "span.optim.precondition" in snap["histograms"]
+    assert "kronscope.optim.root_refresh/" in txt
+    assert "kronscope.optim.precondition/" in txt
+    assert not [k for k in snap["histograms"] if k.startswith("span.optim.")]
 
 
 def test_telemetry_off_adds_zero_hlo_to_optimizer_step():
@@ -388,22 +401,16 @@ def test_telemetry_off_adds_zero_hlo_to_optimizer_step():
     st = sh.shampoo_init(params, cfg)
 
     def compiled_text():
-        return (
-            jax.jit(lambda g, s: sh.shampoo_update(g, s, params, cfg))
-            .lower(grads, st)
-            .compile()
-            .as_text()
-        )
+        return _shampoo_text(params, grads, st, cfg)
 
     off_before = compiled_text()
-    assert "kronscope" not in off_before
+    assert "kronscope.optim.root_refresh" in off_before  # scopes are always on
     telemetry.configure()
     on = compiled_text()
     telemetry.reset()
     off_after = compiled_text()
     assert strip_source_locations(off_before) == strip_source_locations(off_after)
-    assert "kronscope" not in off_after
-    del on  # annotation side of the pin is covered in test_telemetry
+    assert strip_source_locations(on) == strip_source_locations(off_after)
 
 
 # ---------------------------------------------------------------------------

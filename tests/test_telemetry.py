@@ -90,14 +90,15 @@ def test_span_off_is_shared_noop():
 
 
 def test_op_call_records_program_and_stage_spans(tmp_path):
+    """The program and its stages are scopes of the compiled call, not host
+    spans: a span there would time the trace, once."""
     op = engine.KronOp((4, 4), (4, 4))
     x, fs = _problem((4, 4), (4, 4))
     telemetry.configure(jsonl=str(tmp_path / "op.jsonl"))
-    op(x, fs)
+    txt = jax.jit(lambda x, fs: op(x, fs)).lower(x, fs).compile().as_text()
     snap = telemetry.shutdown()
-    hists = snap["histograms"]
-    assert "span.program" in hists
-    assert "span.stage" in hists
+    assert "kronscope.op/kronscope.program/kronscope.stage/" in txt
+    assert not {"span.program", "span.stage"} & set(snap["histograms"])
 
 
 # ---------------------------------------------------------------------------
@@ -259,6 +260,8 @@ def test_straggler_flag_becomes_event():
 
 
 def test_telemetry_off_adds_zero_hlo():
+    """Scopes are part of the program whether telemetry is on or off, so
+    switching it on and off again compiles the same text each time."""
     op = engine.KronOp((4, 4), (4, 4))
     x, fs = _problem((4, 4), (4, 4))
 
@@ -273,31 +276,32 @@ def test_telemetry_off_adds_zero_hlo():
         )
 
     off_before = compiled_text()
-    assert "kronscope" not in off_before
+    assert "kronscope.stage" in strip_source_locations(off_before)
 
     telemetry.configure()
     on = compiled_text()
-    assert "kronscope" in on  # named_scope reaches compiled metadata
-    # stripping source locations keeps what telemetry adds
-    assert "kronscope" in strip_source_locations(on)
-    assert strip_source_locations(on) != strip_source_locations(off_before)
-
     telemetry.reset()
     off_after = compiled_text()
-    # identical compiled HLO up to source line/column numbers (the two
-    # compiles are called from different lines): enabling and disabling
-    # telemetry leaves an untelemetered process where it started
+    # identical compiled HLO up to source line/column numbers (the compiles
+    # are called from different lines)
+    assert strip_source_locations(on) == strip_source_locations(off_before)
     assert strip_source_locations(off_after) == strip_source_locations(off_before)
 
 
 def test_annotate_false_keeps_hlo_clean():
+    """``configure`` has no ``annotate=`` switch any more: spans time the
+    host alone and put nothing into a program traced inside them."""
     op = engine.KronOp((4, 4), (4, 4))
     x, fs = _problem((4, 4), (4, 4))
-    telemetry.configure(annotate=False)
-    txt = (
-        jax.jit(lambda x, fs: op(x, fs)).lower(x, fs).compile().as_text()
-    )
-    assert "kronscope" not in txt
+    with pytest.raises(TypeError):
+        telemetry.configure(annotate=False)
+    telemetry.configure()
+    with telemetry.span("host_region"):
+        txt = (
+            jax.jit(lambda x, fs: op(x, fs)).lower(x, fs).compile().as_text()
+        )
+    assert "kronscope.host_region" not in txt
+    assert "kronscope.stage" in txt  # the program's own scopes
     assert telemetry.snapshot()["spans"] >= 1  # host timing still on
 
 
