@@ -218,21 +218,26 @@ def _record_round_comm(shapes: Sequence[tuple], g_k: int, k: int) -> None:
     telemetry.gauge_set(f"comm.round{k}.elems_per_device", total)
 
 
-def _relocate(y: jax.Array, q_prod: int, g_k: int, model_axis: str) -> jax.Array:
+def _relocate(
+    y: jax.Array, q_prod: int, g_k: int, model_axis: str, rows: int
+) -> jax.Array:
     """One all_to_all relocation (see module docstring).  The index
     arithmetic lives in ``_relocate_batched``; the single-problem case is
     the batch-of-one view (the extra reshape is a layout no-op under jit)."""
-    return _relocate_batched(y[None], q_prod, g_k, model_axis)[0]
+    return _relocate_batched(y[None], q_prod, g_k, model_axis, rows)[0]
 
 
 def _local_multiply_round(
     y: jax.Array, fs: Sequence[jax.Array], backend: str, t_b: int | None
-) -> jax.Array:
+) -> tuple[jax.Array, int]:
     """One round's local multiplies as ONE chain instruction on the unified
     emitter — the same template every other fused path runs.  ``t_b=None``
     is the single-problem body (2-D operands); an int selects the batch-grid
     kernels with ``t_b`` samples per block, tiles re-fitted per round because
-    the round grouping follows the COMM schedule, not the compute plan."""
+    the round grouping follows the COMM schedule, not the compute plan.
+    Also returns the rows of the output's row groups: sigma where the
+    compiled kernel writes the bitcast view ``(B, M/sigma, prod(Q), sigma,
+    S)`` (``emit.stage_view``), else 1, for ``_relocate_batched``."""
     fs = tuple(fs)
     off = 0 if t_b is None else 1
     ps = [int(f.shape[off]) for f in fs]
@@ -244,9 +249,13 @@ def _local_multiply_round(
         kind=emit.MULTIPLY, ps=tuple(ps), qs=tuple(qs), t_m=t_m, t_k=t_k,
         t_b=None if t_b is None else tb,
     )
+    rows = 1
+    if emit.stage_view(instr, y.shape, y.dtype) == "bitcast":
+        m = int(y.shape[-2])
+        rows = emit.y_view_rows(m, m, y.dtype.itemsize)
     try:
         chaos.maybe_fail("round_chain")
-        return emit.run_stage(y, fs, instr, backend=backend)
+        return emit.run_stage(y, fs, instr, backend=backend), rows
     except guard.KronError as e:
         # Round chain cannot fit VMEM even at the degenerate tile (huge
         # Q-growth rounds): fall back to per-factor multiplies — the
@@ -264,7 +273,7 @@ def _local_multiply_round(
         )
         for f in fs:
             y = _sliced_batched(y, f, backend)
-        return y
+        return y, 1
 
 
 # ---------------------------------------------------------------------------
@@ -272,21 +281,26 @@ def _local_multiply_round(
 # ---------------------------------------------------------------------------
 
 
-def _relocate_batched(y: jax.Array, q_prod: int, g_k: int, model_axis: str) -> jax.Array:
+def _relocate_batched(
+    y: jax.Array, q_prod: int, g_k: int, model_axis: str, rows: int
+) -> jax.Array:
     """One all_to_all relocation for the WHOLE batch (the canonical
     implementation — ``_relocate`` is the batch-of-one view).
 
     The collective moves one ``(B, M_loc, C)`` slab per round instead of B
-    separate ``(M_loc, C)`` payloads — same bytes, 1/B the latency."""
+    separate ``(M_loc, C)`` payloads — same bytes, 1/B the latency.
+    ``rows`` > 1 splits M into the row groups ``y`` was written in (a
+    kernel's bitcast view), so that XLA copies the kernel's layout straight
+    into the all_to_all's instead of relayouting it to (M, Q, S) first."""
     chaos.maybe_fail("collective")
     b, m_loc, c = y.shape
     u = c // q_prod
     chunk = q_prod // g_k
-    y5 = y.reshape(b, m_loc, g_k, chunk, u)
-    y5 = jax.lax.all_to_all(y5, model_axis, split_axis=2, concat_axis=2)
-    # axis 2 is now the sender index g_k; target local col = (q_lo*G_K+g_k)*U+s
-    y5 = jnp.swapaxes(y5, 2, 3)
-    return y5.reshape(b, m_loc, c)
+    y6 = y.reshape(b, m_loc // rows, rows, g_k, chunk, u)
+    y6 = jax.lax.all_to_all(y6, model_axis, split_axis=3, concat_axis=3)
+    # axis 3 is now the sender index g_k; target local col = (q_lo*G_K+g_k)*U+s
+    y6 = jnp.swapaxes(y6, 3, 4)
+    return y6.reshape(b, m_loc, c)
 
 
 def _round_tiles(
@@ -335,7 +349,8 @@ def _relocate_batched_t(
 
 
 def _relocate_slab(
-    y: jax.Array, q_prod: int, g_k: int, model_axis: str, n_slabs: int
+    y: jax.Array, q_prod: int, g_k: int, model_axis: str, n_slabs: int,
+    rows: int,
 ) -> jax.Array:
     """Relocate ONE slab (2-D single-problem or 3-D batched).  Pipelined
     schedules (``n_slabs > 1``) get their own chaos site so tests can fail a
@@ -344,8 +359,8 @@ def _relocate_slab(
     if n_slabs > 1:
         chaos.maybe_fail("slab_collective")
     if y.ndim == 2:
-        return _relocate(y, q_prod, g_k, model_axis)
-    return _relocate_batched(y, q_prod, g_k, model_axis)
+        return _relocate(y, q_prod, g_k, model_axis, rows)
+    return _relocate_batched(y, q_prod, g_k, model_axis, rows)
 
 
 def _relocate_slab_t(
@@ -382,17 +397,21 @@ def _slab_round(
     outs: list[jax.Array] = []
     shapes: list[tuple] = []
     pending = None
+
+    def relocate(y_rows):
+        return _relocate_slab(y_rows[0], qprod, g_k, model_axis, n, y_rows[1])
+
     for s in range(n):
-        y_s = _local_multiply_round(slabs[s], fs, backend, t_b)
+        y_s, rows = _local_multiply_round(slabs[s], fs, backend, t_b)
         shapes.append(tuple(int(d) for d in y_s.shape))
         if pending is not None:
-            outs.append(_relocate_slab(pending, qprod, g_k, model_axis, n))
+            outs.append(relocate(pending))
         if g_k > 1:
-            pending = y_s
+            pending = (y_s, rows)
         else:
             outs.append(y_s)
     if pending is not None:
-        outs.append(_relocate_slab(pending, qprod, g_k, model_axis, n))
+        outs.append(relocate(pending))
     if g_k > 1 and record:
         _record_round_comm(shapes, g_k, k)
     return outs

@@ -1267,9 +1267,12 @@ class KronOp:
         ``m`` rows (default: the op's row hint, else 16): ``pallas(t_b, t_m,
         t_k)`` with the legal tiles the emitter uses, or ``xla`` where no
         legal kernel tiling fits VMEM — for the forward kernel and for the
-        stage-backward kernel.  Decided from shapes alone, never from a
-        failed compile.  One ``(stage, forward, backward)`` triple per
-        stage; empty for mesh ops without a stage plan."""
+        stage-backward kernel.  K-tiled kernels add the view of their
+        y-side array (``emit.stage_view``): ``:bitcast`` where it is the
+        flat array's bytes, ``:relayout`` where XLA relayouts it.  Decided
+        from shapes alone, never from a failed compile.  One ``(stage,
+        forward, backward)`` triple per stage; empty for mesh ops without a
+        stage plan."""
         m = self._default_rows() if m is None else int(m)
         itemsize = jnp.dtype(dtype).itemsize
         per_sample = self.batch is not None and not self.shared_factors
@@ -1285,17 +1288,19 @@ class KronOp:
         if plan is None:
             return []
 
-        def how(tiles):
-            return "xla" if tiles is None else f"pallas{tiles}"
+        def how(ins, shape, grad):
+            tiles = emit.stage_tiles(ins, shape, dtype, grad=grad)
+            if tiles is None:
+                return "xla"
+            view = emit.stage_view(ins, shape, dtype, grad=grad)
+            return f"pallas{tiles}" + (f":{view}" if view else "")
 
         cols, out = self.k, []
         for ins in _lowered(plan, self.ps, self.qs, per_sample).instrs:
             shape = lead + (m, cols)
-            out.append((
-                ins.describe(),
-                how(emit.stage_tiles(ins, shape, dtype)),
-                how(emit.stage_tiles(ins, shape, dtype, grad=True)),
-            ))
+            out.append(
+                (ins.describe(), how(ins, shape, False), how(ins, shape, True))
+            )
             cols = cols // ins.pprod * ins.qprod
         return out
 

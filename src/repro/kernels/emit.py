@@ -32,7 +32,7 @@ import dataclasses
 import functools
 import math
 import os
-from typing import Sequence
+from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -414,6 +414,16 @@ def max_n_fused(t_k: int, p: int) -> int:
 # swap plus one GEMM: (s*p, L) -> (p, s*L) -> F^T @ -> (q*s, L).  When L is
 # not a multiple of 128 the lanes cannot absorb s, and the step runs as a
 # batched GEMM over s instead.  The transposed step is the exact inverse.
+#
+# K-tiled blocks write (and the backward kernels read) the chain output in
+# HBM through a view that is a bitcast of the flat (B, M, prod(Q)*S) array:
+# that array is tiled (sigma, 128) over (M, C), so its bytes run
+# (b, M/sigma, q, S/128, sigma, 128), which is the (B, M/sigma, prod(Q),
+# sigma, S) view tiled (sigma, 128) over (sigma, S).  A block of it is the
+# tile (t_m/sigma, R, sigma, ts) in VMEM, reached from W by splitting its
+# lanes (m, slice) and swapping two leading dims.  Rows that cannot be cut
+# into sigma-row groups fall back to the (B, M, prod(Q), S) view, which
+# XLA relayouts to and from the flat array (``_y_view``).
 
 LANE = 128
 KERNEL_NAMES = ("kron_chain_fwd", "kron_chain_bwd", "kron_stage_grad")
@@ -426,6 +436,29 @@ def _pad_to(n: int, m: int) -> int:
 def _sublane(itemsize: int) -> int:
     """Rows of one native VMEM tile: 8 for 32-bit dtypes, 16 for 16-bit."""
     return 8 * max(1, 4 // int(itemsize))
+
+
+# Rows of one (rows, 128) tile of a flat (B, M, C) kernel operand in HBM, by
+# itemsize, as the v5e compiler lays it out: T(8,128) for f32, and
+# T(8,128)(2,1) for bf16 (pairs of rows packed into 32-bit words).
+_HBM_ROWS = {4: 8, 2: 8}
+
+
+def y_view_rows(m: int, t_m: int, itemsize: int) -> int | None:
+    """Rows sigma of the bitcast y-side view ``(B, M/sigma, prod(Q), sigma,
+    S)`` for K-tiled blocks of ``t_m`` of ``m`` rows, or None where it has
+    none.  sigma is the HBM tile's rows when they divide both.  A block of
+    all of fewer rows than that takes sigma = M: the flat array and the view
+    then both have M rows in their minor-but-one dim, which the compiler
+    tiles alike (a tile of M rows, or M padded), so their bytes agree."""
+    rows = _HBM_ROWS.get(int(itemsize))
+    if rows is None:
+        return None
+    if m % rows == 0 and t_m % rows == 0:
+        return rows
+    if t_m == m < rows:
+        return m
+    return None
 
 
 def _tiled_bytes(shape, itemsize: int = 4) -> int:
@@ -468,20 +501,28 @@ def _w_to_x(w, t_m: int, ts: int, dense: bool):
     return jnp.swapaxes(v, 0, 1).reshape(ts * r, t_m).T
 
 
-def _y_to_w(v, ts: int, dense: bool):
-    """y-layout tile (t_m, R*ts) or (t_m, R..., ts) (slice minor) -> W."""
-    t_m = v.shape[0]
+def _y_to_w(v, t_m: int, ts: int, dense: bool, rows: int | None):
+    """y-layout tile (slice minor) -> W: the flat (t_m, R*ts), (t_m, R, ts),
+    or, with ``rows`` = sigma, the bitcast view's (t_m/sigma, R, sigma, ts)."""
     if not dense:
         return v.T
+    if rows:
+        v = jnp.swapaxes(v, 0, 1)  # (R, t_m/sigma, sigma, ts)
+        return v.reshape(v.shape[0], t_m * ts)
     r = math.prod(v.shape[1:]) // ts
     return jnp.swapaxes(v.reshape(t_m, r, ts), 0, 1).reshape(r, t_m * ts)
 
 
-def _w_to_y(w, t_m: int, ts: int, dense: bool):
-    """W -> y layout: (t_m, R, ts) when dense, else the flat (t_m, R*ts)."""
+def _w_to_y(w, t_m: int, ts: int, dense: bool, rows: int | None):
+    """W -> y layout, the inverse of ``_y_to_w`` (a dense block without
+    ``rows`` gets the (t_m, R, ts) tile, reshaped to its block by the
+    kernel)."""
     if not dense:
         return w.T
-    return jnp.swapaxes(w.reshape(w.shape[0], t_m, ts), 0, 1)
+    r = w.shape[0]
+    if rows:
+        return jnp.swapaxes(w.reshape(r, t_m // rows, rows, ts), 0, 1)
+    return jnp.swapaxes(w.reshape(r, t_m, ts), 0, 1)
 
 
 def _step(w, f, acc, merge: bool):
@@ -535,8 +576,8 @@ def _merges(lanes: int, interpret: bool) -> bool:
 
 
 def _chain_kernel(
-    x_ref, *refs, n: int, ts: int, dense: bool, direction: str, acc_dtype,
-    interpret: bool,
+    x_ref, *refs, n: int, t_m: int, ts: int, dense: bool, rows: int | None,
+    direction: str, acc_dtype, interpret: bool,
 ):
     """One parameterized kernel body for every fused chain.
 
@@ -547,7 +588,6 @@ def _chain_kernel(
     the sequential Q-tile grid axis.
     """
     f_refs, y_ref = refs[:n], refs[n]
-    t_m = x_ref.shape[1]
     jq = pl.program_id(3) if direction == "bwd" else None
     merge = _merges(t_m * ts if dense else t_m, interpret)
 
@@ -557,10 +597,10 @@ def _chain_kernel(
             w = _x_to_w(x_ref[ib].astype(acc_dtype), ts, dense)
             for f in fs:
                 w = _step(w, f, acc_dtype, merge)
-            y = _w_to_y(w, t_m, ts, dense).reshape(y_ref.shape[1:])
+            y = _w_to_y(w, t_m, ts, dense, rows).reshape(y_ref.shape[1:])
             y_ref[ib] = y.astype(y_ref.dtype)
             return carry
-        w = _y_to_w(x_ref[ib].astype(acc_dtype), ts, dense)
+        w = _y_to_w(x_ref[ib].astype(acc_dtype), t_m, ts, dense, rows)
         for f in reversed(fs):
             w = _step_t(w, f, acc_dtype, merge)
         # y_ref is acc_dtype (cast to the input dtype by the wrapper) so the
@@ -590,6 +630,80 @@ def _q_tiling(qs, t_qs, n):
         return (jq // strides[i]) % nq[i]
 
     return math.prod(nq), q_digit
+
+
+@dataclasses.dataclass(frozen=True)
+class _YView:
+    """A kernel's y-side array in HBM: the view the kernel sees, its block,
+    the block index as a function of ``(ib, im, jq, j)``, and the rows sigma
+    of a view that is a bitcast of the flat array (None otherwise)."""
+
+    shape: tuple[int, ...]
+    block: tuple[int, ...]
+    index: Callable[..., tuple]
+    rows: int | None = None
+
+    def of(self, y):
+        """The flat ``(B, M, C)`` array in this view."""
+        if self.rows:
+            b, m_rows, qprod, rows, s = self.shape
+            return jnp.swapaxes(y.reshape(b, m_rows, rows, qprod, s), 2, 3)
+        return y.reshape(self.shape)
+
+    def flat(self, v):
+        """Inverse of ``of``."""
+        if self.rows:
+            v = jnp.swapaxes(v, 2, 3)
+            return v.reshape(v.shape[0], v.shape[1] * v.shape[2], -1)
+        return v.reshape(v.shape[0], v.shape[1], -1)
+
+
+def _y_block_shape(t_m, qprod, ts, *, flat: bool, rows: int | None):
+    """One sample's y-side block (and VMEM tile) for the views of ``_y_view``."""
+    if flat:
+        return (t_m, qprod * ts)
+    if rows:
+        return (t_m // rows, qprod, rows, ts)
+    return (t_m, qprod, ts)
+
+
+def _y_view(b, m, qs, t_qs, s_out, *, t_b, t_m, ts, flat, itemsize) -> _YView:
+    """The y-side view of every kernel: the forward chain's output, the
+    backward chain's input and the stage backward's ``dy``.
+
+    Whole-K blocks see the flat ``(B, M, prod(Q)*S)`` array.  K-tiled blocks
+    see ``(B, M/sigma, prod(Q), sigma, S)``, a bitcast of it
+    (``y_view_rows``), else ``(B, M, prod(Q), S)``, which XLA relayouts; the
+    trace-time choice counts ``emit.y_view.bitcast`` / ``.relayout``.
+    Q-tiled blocks (interpret only) take one axis per Q digit."""
+    qprod = math.prod(qs)
+    if flat:
+        return _YView(
+            (b, m, qprod * s_out), (t_b, t_m, qprod * s_out),
+            lambda ib, im, jq, j: (ib, im, 0),
+        )
+    if tuple(t_qs) != tuple(qs):
+        n = len(qs)
+        _, q_digit = _q_tiling(qs, t_qs, n)
+        telemetry.counter_inc("emit.y_view.relayout")
+        return _YView(
+            (b, m) + tuple(reversed(qs)) + (s_out,),
+            (t_b, t_m) + tuple(reversed(t_qs)) + (ts,),
+            lambda ib, im, jq, j: (ib, im) + tuple(
+                q_digit(jq, i) for i in reversed(range(n))
+            ) + (j,),
+        )
+    rows = y_view_rows(m, t_m, itemsize)
+    telemetry.counter_inc(f"emit.y_view.{'bitcast' if rows else 'relayout'}")
+    block = (t_b,) + _y_block_shape(t_m, qprod, ts, flat=False, rows=rows)
+    if rows:
+        return _YView(
+            (b, m // rows, qprod, rows, s_out), block,
+            lambda ib, im, jq, j: (ib, im, 0, 0, j), rows,
+        )
+    return _YView(
+        (b, m, qprod, s_out), block, lambda ib, im, jq, j: (ib, im, 0, j)
+    )
 
 
 def _states(ps, qs, direction):
@@ -629,21 +743,26 @@ def _step_bytes(r_in, p, q, t_m, ts, dense, acc_bytes) -> int:
 def chain_vmem_bytes(
     t_b: int, t_m: int, t_k: int, ps, qs, *, direction: str, flat: bool,
     in_bytes: int, out_bytes: int, acc_bytes: int = 4, grad: bool = False,
+    m: int | None = None,
 ) -> int:
     """VMEM one grid step of the chain (or stage-gradient) kernel needs, with
     every buffer padded to (sublane, 128) tiles: the double-buffered blocks
     the pipeline streams, plus the live working set of one sample (blocks are
-    walked a sample at a time).  The emitter's legality check and the planner
-    both read this one model."""
+    walked a sample at a time).  ``m`` is the array's rows (default: one
+    block holds them all); with ``t_m`` it picks the y-side view
+    (``y_view_rows``).  The emitter's legality check and the planner both
+    read this one model."""
     ps, qs = tuple(ps), tuple(qs)
     pprod, qprod = math.prod(ps), math.prod(qs)
     ts = t_k // pprod
     dense = not flat or ts % LANE == 0
+    rows = None if flat else y_view_rows(t_m if m is None else m, t_m, in_bytes)
+    y_shape = _y_block_shape(t_m, qprod, ts, flat=flat, rows=rows)
     x_blk = _tiled_bytes((t_m, t_k), in_bytes)
-    y_blk = _tiled_bytes((t_m, qprod * ts) if flat else (t_m, qprod, ts), in_bytes)
+    y_blk = _tiled_bytes(y_shape, in_bytes)
     f_blk = sum(_tiled_bytes((p, q), in_bytes) for p, q in zip(ps, qs))
     x_acc = _tiled_bytes((t_m, t_k), acc_bytes)
-    y_acc = _tiled_bytes((t_m, qprod * ts), acc_bytes)
+    y_acc = _tiled_bytes(y_shape if rows else (t_m, qprod * ts), acc_bytes)
     if grad:
         blocks = 2 * x_blk + y_blk + f_blk + x_acc + f_blk * acc_bytes // in_bytes
         states = _states(ps, qs, "fwd")
@@ -655,9 +774,7 @@ def chain_vmem_bytes(
         live = kept + steps + x_acc + y_acc
     else:
         if direction == "fwd":
-            blocks = x_blk + f_blk + _tiled_bytes(
-                (t_m, qprod * ts) if flat else (t_m, qprod, ts), out_bytes
-            )
+            blocks = x_blk + f_blk + _tiled_bytes(y_shape, out_bytes)
             pairs = list(zip(_states(ps, qs, "fwd"), ps, qs))
         else:
             blocks = y_blk + f_blk + _tiled_bytes((t_m, t_k), out_bytes)
@@ -678,7 +795,8 @@ def tpu_block_error(
     A block's last two dims must be tile multiples or the full extent: rows
     ``t_m`` a multiple of the sublane tile (or all of M), and the slice
     count ``ts = t_k / prod(P)`` of a K-tiled block a multiple of 128 — the
-    output block then is (t_m, prod(Q), ts).  The kernels tile Q only in
+    y-side block then is (t_m/sigma, prod(Q), sigma, ts) of the bitcast view
+    (``y_view_rows``), else (t_m, prod(Q), ts).  The kernels tile Q only in
     interpret mode (a Q-tiled output block has no legal relayout)."""
     pprod = math.prod(ps)
     ts, s_out = t_k // pprod, k // pprod
@@ -721,7 +839,7 @@ def legal_tiles(
                 nbytes = chain_vmem_bytes(
                     tb, tm, tk, ps, qs, direction=direction, flat=flat,
                     in_bytes=itemsize, out_bytes=itemsize, acc_bytes=acc_bytes,
-                    grad=grad,
+                    grad=grad, m=m,
                 )
                 if nbytes > budget:
                     continue
@@ -832,7 +950,7 @@ def chain_pallas(
     need = chain_vmem_bytes(
         t_b, t_m, t_k, ps, t_qs, direction=direction, flat=flat,
         in_bytes=x.dtype.itemsize, out_bytes=x.dtype.itemsize,
-        acc_bytes=jnp.dtype(acc).itemsize,
+        acc_bytes=jnp.dtype(acc).itemsize, m=m,
     )
     if need > vmem_budget_elems * 4:
         raise VmemOverflowError(
@@ -843,31 +961,14 @@ def chain_pallas(
     # Composite Q-tile grid axis: one mixed-radix digit per factor, factor 0
     # (applied first) minor — matching the output layout (q_n, ..., q_1, s).
     nq_tiles, q_digit = _q_tiling(qs, t_qs, n)
-    # The y-side view: flat (B, M, prod(Q)*S) for whole-K blocks, else
-    # (B, M, prod(Q), S) with (t_m, prod(Q), ts) blocks, or — Q-tiled,
-    # interpret only — one axis per Q digit, each tiled by its own digit.
-    if flat:
-        y_view, y_block = (b, m, qprod * s_out), (t_b, t_m, qprod * s_out)
-
-        def y_index(ib, im, jq, j):
-            return (ib, im, 0)
-    elif q_full:
-        y_view, y_block = (b, m, qprod, s_out), (t_b, t_m, qprod, ts)
-
-        def y_index(ib, im, jq, j):
-            return (ib, im, 0, j)
-    else:
-        y_view = (b, m) + tuple(reversed(qs)) + (s_out,)
-        y_block = (t_b, t_m) + tuple(reversed(t_qs)) + (ts,)
-
-        def y_index(ib, im, jq, j):
-            return (ib, im) + tuple(
-                q_digit(jq, i) for i in reversed(range(n))
-            ) + (j,)
+    yv = _y_view(
+        b, m, qs, t_qs, s_out, t_b=t_b, t_m=t_m, ts=ts, flat=flat,
+        itemsize=x.dtype.itemsize,
+    )
 
     kernel = functools.partial(
-        _chain_kernel, n=n, ts=ts, dense=dense, direction=direction,
-        acc_dtype=acc, interpret=interpret,
+        _chain_kernel, n=n, t_m=t_m, ts=ts, dense=dense, rows=yv.rows,
+        direction=direction, acc_dtype=acc, interpret=interpret,
     )
     params = _compiler_params(interpret)
     if direction == "fwd":
@@ -886,18 +987,18 @@ def chain_pallas(
             kernel,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(y_block, y_index),
-            out_shape=jax.ShapeDtypeStruct(y_view, x.dtype),
+            out_specs=pl.BlockSpec(yv.block, yv.index),
+            out_shape=jax.ShapeDtypeStruct(yv.shape, x.dtype),
             interpret=interpret,
             compiler_params=params,
             name=KERNEL_NAMES[0],
         )(x, *factors)
-        return out.reshape(b, m, qprod * s_out)
+        return yv.flat(out)
 
     # bwd: Q innermost — the sequential accumulation dim.
     grid = (b // t_b, m // t_m, k // t_k, nq_tiles)
     in_specs = [
-        pl.BlockSpec(y_block, lambda ib, im, j, jq: y_index(ib, im, jq, j))
+        pl.BlockSpec(yv.block, lambda ib, im, j, jq: yv.index(ib, im, jq, j))
     ]
     for i in range(n):
         in_specs.append(
@@ -917,7 +1018,7 @@ def chain_pallas(
         interpret=interpret,
         compiler_params=params,
         name=KERNEL_NAMES[1],
-    )(x.reshape(y_view), *factors)
+    )(yv.of(x), *factors)
     return out.astype(x.dtype)
 
 
@@ -927,8 +1028,8 @@ def chain_pallas(
 
 
 def _grad_kernel(
-    x_ref, dy_ref, *refs, n: int, ts: int, dense: bool, acc_dtype,
-    interpret: bool,
+    x_ref, dy_ref, *refs, n: int, ts: int, dense: bool, rows: int | None,
+    acc_dtype, interpret: bool,
 ):
     """Full stage backward: rematerialize the forward chain in VMEM, then
     walk the transposed chain computing the input gradient and every factor
@@ -953,7 +1054,7 @@ def _grad_kernel(
         us = [_x_to_w(x_ref[ib].astype(acc_dtype), ts, dense)]
         for f in fs[:-1]:
             us.append(_step(us[-1], f, acc_dtype, merge))
-        g = _y_to_w(dy_ref[ib].astype(acc_dtype), ts, dense)
+        g = _y_to_w(dy_ref[ib].astype(acc_dtype), t_m, ts, dense, rows)
         for idx in reversed(range(n)):
             df_part, g = _step_t(g, fs[idx], acc_dtype, merge, u=us[idx])
 
@@ -1020,7 +1121,7 @@ def grad_pallas(
     need = chain_vmem_bytes(
         t_b, t_m, t_k, ps, qs, direction="fwd", flat=flat,
         in_bytes=x.dtype.itemsize, out_bytes=x.dtype.itemsize,
-        acc_bytes=jnp.dtype(acc).itemsize, grad=True,
+        acc_bytes=jnp.dtype(acc).itemsize, grad=True, m=m,
     )
     if need > vmem_budget_elems * 4:
         raise VmemOverflowError(
@@ -1030,20 +1131,13 @@ def grad_pallas(
         )
 
     grid = (b // t_b, m // t_m, k // t_k)
-    if flat:
-        dy_view, dy_block = (b, m, qprod * s_out), (t_b, t_m, qprod * s_out)
-
-        def dy_index(ib, im, j):
-            return (ib, im, 0)
-    else:
-        dy_view, dy_block = (b, m, qprod, s_out), (t_b, t_m, qprod, ts)
-
-        def dy_index(ib, im, j):
-            return (ib, im, 0, j)
-
+    yv = _y_view(
+        b, m, qs, qs, s_out, t_b=t_b, t_m=t_m, ts=ts, flat=flat,
+        itemsize=dy.dtype.itemsize,
+    )
     in_specs = [
         pl.BlockSpec((t_b, t_m, t_k), lambda ib, im, j: (ib, im, j)),
-        pl.BlockSpec(dy_block, dy_index),
+        pl.BlockSpec(yv.block, lambda ib, im, j: yv.index(ib, im, 0, j)),
     ]
     for p, q in zip(ps, qs):
         in_specs.append(pl.BlockSpec((t_b, p, q), lambda ib, im, j: (ib, 0, 0)))
@@ -1054,8 +1148,8 @@ def grad_pallas(
         out_shapes.append(jax.ShapeDtypeStruct((b, p, q), acc))
     outs = pl.pallas_call(
         functools.partial(
-            _grad_kernel, n=n, ts=ts, dense=dense, acc_dtype=acc,
-            interpret=interpret,
+            _grad_kernel, n=n, ts=ts, dense=dense, rows=yv.rows,
+            acc_dtype=acc, interpret=interpret,
         ),
         grid=grid,
         in_specs=in_specs,
@@ -1064,7 +1158,7 @@ def grad_pallas(
         interpret=interpret,
         compiler_params=_compiler_params(interpret),
         name=KERNEL_NAMES[2],
-    )(x, dy.reshape(dy_view), *factors)
+    )(x, yv.of(dy), *factors)
     return outs[0], tuple(outs[1:])
 
 
@@ -1312,12 +1406,7 @@ def stage_tiles(
     Decided from shapes and dtype alone, before anything is compiled.
     ``grad=True`` asks about the stage-backward kernel (``y_shape`` is then
     the stage input's)."""
-    ps, qs = instr.ps, instr.qs
-    if instr.kind == PREKRON:
-        ps, qs = (math.prod(ps),), (math.prod(qs),)
-    b, m, cols = (1,) * (3 - len(y_shape)) + tuple(int(d) for d in y_shape)
-    direction = "fwd" if grad else instr.direction
-    k = cols if direction == "fwd" else cols // math.prod(qs) * math.prod(ps)
+    direction, b, m, k, ps, qs = _stage_problem(instr, y_shape, grad)
     dtype = jnp.dtype(dtype)
     return legal_tiles(
         direction, b, m, k, ps, qs, t_b=instr.t_b or 1, t_m=instr.t_m,
@@ -1325,6 +1414,39 @@ def stage_tiles(
         acc_bytes=jnp.dtype(_resolve_acc(instr.acc_dtype, dtype)).itemsize,
         grad=grad, budget_bytes=vmem_budget_elems * 4,
     )
+
+
+def _stage_problem(instr: StageInstr, y_shape, grad: bool):
+    """(direction, b, m, k, ps, qs) of the kernel that runs ``instr``."""
+    ps, qs = instr.ps, instr.qs
+    if instr.kind == PREKRON:
+        ps, qs = (math.prod(ps),), (math.prod(qs),)
+    b, m, cols = (1,) * (3 - len(y_shape)) + tuple(int(d) for d in y_shape)
+    direction = "fwd" if grad else instr.direction
+    k = cols if direction == "fwd" else cols // math.prod(qs) * math.prod(ps)
+    return direction, b, m, k, ps, qs
+
+
+def stage_view(
+    instr: StageInstr, y_shape, dtype, *, grad: bool = False,
+    vmem_budget_elems: int = VMEM_BUDGET_ELEMS,
+) -> str | None:
+    """How the compiled kernel of ``instr`` (arguments as ``stage_tiles``)
+    sees its y-side array: ``"bitcast"`` for K-tiled blocks of the view
+    that is the flat array's bytes, ``"relayout"`` for K-tiled blocks of
+    the view XLA relayouts to and from it, None for whole-K blocks (which
+    see the flat array) or a stage on the XLA executor."""
+    tiles = stage_tiles(
+        instr, y_shape, dtype, grad=grad, vmem_budget_elems=vmem_budget_elems
+    )
+    if tiles is None:
+        return None
+    _, _, m, k, _, _ = _stage_problem(instr, y_shape, grad)
+    _, t_m, t_k = tiles
+    if t_k == k:
+        return None
+    rows = y_view_rows(m, t_m, jnp.dtype(dtype).itemsize)
+    return "bitcast" if rows else "relayout"
 
 
 def run_stage(
@@ -1511,6 +1633,8 @@ __all__ = [
     "chain_vmem_bytes",
     "legal_tiles",
     "stage_tiles",
+    "stage_view",
+    "y_view_rows",
     "tpu_block_error",
     "KERNEL_NAMES",
     "fused_growth",

@@ -22,6 +22,7 @@ from repro.core.autotune import lower, make_plan
 from repro.core.engine import KronOp
 from repro.core.kron import KronProblem, kron_matrix
 from repro.kernels import emit
+from repro.runtime import telemetry
 
 jax.config.update("jax_enable_x64", True)
 
@@ -396,6 +397,81 @@ def test_no_legal_tiling_routes_stage_to_xla_in_describe():
     assert "xla" in op.describe().split(":: exec[")[1]
     assert all(ex != "xla" for _, ex, _ in
                KronOp((8,) * 3, (8,) * 3, m=16, backend="pallas").stage_executors())
+
+
+# (M, t_m, dtype, view): 8-row groups, a block of all of fewer than 8 rows,
+# and M = 20 with no 8-row groups (the view XLA relayouts).
+Y_VIEW_CASES = [
+    (16, 8, jnp.float32, "bitcast"),
+    (4, 4, jnp.float32, "bitcast"),
+    (20, 20, jnp.float32, "relayout"),
+    (16, 16, jnp.bfloat16, "bitcast"),
+]
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd", "grad"])
+@pytest.mark.parametrize(
+    "m,t_m,dtype,view", Y_VIEW_CASES,
+    ids=["m16", "m4", "m20", "m16_bf16"],
+)
+def test_k_tiled_y_view_matches_xla_executor(kernel, m, t_m, dtype, view):
+    """K-tiled blocks (K = 4096, 2048-column tiles of one 16x16 factor,
+    ts = 128) read and write the y-side array through the view
+    ``y_view_rows`` picks; every kernel matches the XLA executor, and the
+    trace-time choice is counted."""
+    x = _mk(3, m, (16,) * 3, (16,) * 3)[0].astype(dtype)
+    g = _mk(5, m, (16,) * 3, (16,) * 3)[0].astype(dtype)
+    f = _mk(4, 16, (16,), (16,))[1][0].astype(dtype)
+    kw = dict(t_b=1, t_m=t_m, t_k=2048, interpret=True)
+    assert emit.y_view_rows(m, t_m, jnp.dtype(dtype).itemsize) == (
+        min(m, 8) if view == "bitcast" else None
+    )
+    tol = 1e-4 if dtype == jnp.float32 else 1e-2
+    tol = dict(rtol=tol, atol=tol)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))  # noqa: E731
+    telemetry.configure()
+    try:
+        if kernel == "grad":
+            emit.grad_pallas.clear_cache()
+            dx, (df,) = emit.grad_pallas(x[None], g[None], f[None], **kw)
+            dx_ref, (df_ref,) = emit._grad_xla(x, g, (f,))
+            np.testing.assert_allclose(f32(dx[0]), f32(dx_ref), **tol)
+            np.testing.assert_allclose(f32(df[0]), f32(df_ref), **tol)
+        else:
+            emit.chain_pallas.clear_cache()
+            y_in = x if kernel == "fwd" else g
+            got = emit.chain_pallas(y_in[None], f[None], direction=kernel, **kw)[0]
+            want = emit._chain_xla(y_in, (f,), direction=kernel)
+            np.testing.assert_allclose(f32(got), f32(want), **tol)
+        counters = telemetry.snapshot()["counters"]
+    finally:
+        telemetry.reset()
+    other = "relayout" if view == "bitcast" else "bitcast"
+    assert counters.get(f"emit.y_view.{view}", 0) >= 1
+    assert counters.get(f"emit.y_view.{other}", 0) == 0
+
+
+def test_stage_view_pins_gp26_bitcast_and_m20_relayout():
+    """Table 4 row 26 (M = 16, six 16x16 factors) runs three K-tiled
+    prekron stages through the bitcast view, forward and backward; at
+    M = 20 the same stages keep the relayouted view.  Whole-K stages name
+    no view."""
+    gp26 = KronOp((16,) * 6, (16,) * 6, m=16, backend="pallas", enable_prekron=True)
+    execs = gp26.stage_executors()
+    assert len(execs) == 3
+    assert all(
+        fwd.endswith(":bitcast") and grad.endswith(":bitcast")
+        for _, fwd, grad in execs
+    ), execs
+    assert ":bitcast" in gp26.describe().split(":: exec[")[1]
+    m20 = KronOp((16,) * 4, (16,) * 4, m=20, backend="pallas", enable_prekron=True)
+    assert all(
+        fwd.endswith(":relayout") and grad.endswith(":relayout")
+        for _, fwd, grad in m20.stage_executors()
+    )
+    instr = emit.StageInstr(emit.PREKRON, (16, 16), (16, 16), (0, 1), t_m=8)
+    assert emit.stage_view(instr, (16, 16 ** 6), jnp.float32) == "bitcast"
+    assert emit.stage_view(instr, (16, 16 ** 3), jnp.float32) is None  # whole K
 
 
 @pytest.mark.parametrize(

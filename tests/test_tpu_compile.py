@@ -15,6 +15,7 @@ library, and every test worker imports this file.
 """
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.core import autotune
+from repro.core.engine import KronOp
 from repro.core.kron import KronProblem
 from repro.core.layers import KronLinearSpec
 from repro.kernels import emit
@@ -129,3 +131,84 @@ def test_main_path_kernels_compile_for_v5e(one_chip, kernel, name, m, ps, qs, dt
             seen.add(key)
             hlo = _compile(kernel, instr, m, cols, dtype, one_chip)
             assert "tpu_custom_call" in hlo, f"{name} {kernel} {instr.describe()}"
+
+
+# ---------------------------------------------------------------------------
+# The y-side view in HBM: no relayout between Kron stages
+# ---------------------------------------------------------------------------
+
+_INSTR = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][a-z0-9\-]*)\((.*?)\)")
+
+
+def _instrs(hlo: str) -> dict:
+    """name -> (opcode, element count of the result, operand names)."""
+    out = {}
+    for line in hlo.splitlines():
+        hit = _INSTR.match(line)
+        if hit is None:
+            continue
+        name, shape, opcode, operands = hit.groups()
+        dims = re.match(r"\(?\w+\[([\d,]*)\]", shape)
+        count = math.prod(int(d) for d in dims.group(1).split(",") if d) if dims else 0
+        out[name] = (opcode, count, re.findall(r"%([\w.\-]+)", operands))
+    return out
+
+
+def _kron_programs(m, dtype, sharding):
+    """The compiled forward, value_and_grad (the stage backward) and grad in
+    X alone (the transposed chain) of a (16,)*4 KronOp planned for the chip:
+    two prekron stages of 256x256, s_out = 256, K-tiled at ts = 128."""
+    ps = (16,) * 4
+    op = KronOp(ps, ps, m=m, backend="pallas", enable_prekron=True,
+                dtype_bytes=jnp.dtype(dtype).itemsize)
+    shape = lambda *s: jax.ShapeDtypeStruct(s, dtype, sharding=sharding)  # noqa: E731
+    x, ct = shape(m, 16 ** 4), shape(m, 16 ** 4)
+    fs = tuple(shape(16, 16) for _ in ps)
+
+    def loss(x, fs, ct):
+        return jnp.vdot(op(x, fs).astype(jnp.float32), ct.astype(jnp.float32))
+
+    programs = {
+        "fwd": (lambda x, fs: op(x, fs), (x, fs)),
+        "value_and_grad": (jax.value_and_grad(loss, argnums=(0, 1)), (x, fs, ct)),
+        "grad_x": (jax.grad(loss), (x, fs, ct)),
+    }
+    return op, {
+        name: jax.jit(fn).lower(*args).compile().as_text()
+        for name, (fn, args) in programs.items()
+    }
+
+
+@pytest.mark.parametrize(
+    "m,dtype,view",
+    [(16, jnp.float32, "bitcast"), (16, jnp.bfloat16, "bitcast"),
+     (20, jnp.float32, "relayout")],
+    ids=["m16", "m16_bf16", "m20"],
+)
+def test_kron_stages_pass_outputs_as_bitcasts(one_chip, monkeypatch, m, dtype, view):
+    """Each K-tiled kernel reads and writes the flat (M, K) array's bytes:
+    every (M, K)-sized kernel operand is a bitcast or a parameter, and no
+    reshape, copy or transpose of that size is left in the program (a
+    ``copy-start`` only moves an array between memory spaces).  M = 20 has
+    no 8-row groups: its kernels keep the relayouted view and compile."""
+    monkeypatch.setattr(emit, "_on_tpu", lambda: True)  # compile, not interpret
+    with jax.enable_x64(False):
+        op, hlos = _kron_programs(m, dtype, one_chip)
+    assert {fwd.split(":")[-1] for _, fwd, _ in op.stage_executors()} == {view}
+    size = m * 16 ** 4
+    for name, hlo in hlos.items():
+        instrs = _instrs(hlo)
+        kernels = [i for i in instrs if i.startswith(emit.KERNEL_NAMES)]
+        assert kernels, name
+        if view == "relayout":
+            continue
+        for k in kernels:
+            for operand in instrs[k][2]:
+                opcode, count, _ = instrs[operand]
+                if count == size:
+                    assert opcode in ("bitcast", "parameter"), (name, k, operand, opcode)
+        relayouts = [
+            i for i, (opcode, count, _) in instrs.items()
+            if opcode in ("reshape", "copy", "transpose") and count == size
+        ]
+        assert not relayouts, (name, relayouts)
