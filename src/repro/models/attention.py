@@ -78,8 +78,9 @@ def _project_qkv(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array):
     return q, k, v
 
 
-def _grouped_scores(q: jax.Array, k: jax.Array) -> jax.Array:
-    """q (B,Sq,H,hd), k (B,Sk,Hkv,hd) -> (B,Hkv,G,Sq,Sk) in f32."""
+def _grouped_scores(q: jax.Array, k: jax.Array, denom: float | None = None) -> jax.Array:
+    """q (B,Sq,H,hd), k (B,Sk,Hkv,hd) -> (B,Hkv,G,Sq,Sk) in f32, divided by
+    ``denom`` (default ``sqrt(hd)``)."""
     b, sq, h, hd = q.shape
     hkv = k.shape[2]
     g = h // hkv
@@ -88,7 +89,7 @@ def _grouped_scores(q: jax.Array, k: jax.Array) -> jax.Array:
         "bqhgd,bkhd->bhgqk",
         qg.astype(jnp.float32),
         k.astype(jnp.float32),
-    ) / math.sqrt(hd)
+    ) / (math.sqrt(hd) if denom is None else denom)
 
 
 def _grouped_out(probs: jax.Array, v: jax.Array) -> jax.Array:
@@ -99,18 +100,20 @@ def _grouped_out(probs: jax.Array, v: jax.Array) -> jax.Array:
     return out.reshape(b, sq, hkv * g, hd)
 
 
-def attn_forward(
+def causal_attention(
     cfg: ModelConfig,
-    p: dict,
-    x: jax.Array,
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
     positions: jax.Array,
     *,
     q_chunk: int = 1024,
-    return_kv: bool = False,
-):
-    """Causal full-sequence attention (train / prefill)."""
-    b, s, _ = x.shape
-    q, k, v = _project_qkv(cfg, p, x, positions)
+    denom: float | None = None,
+) -> jax.Array:
+    """The causal softmax core: q (B,S,H,dqk), k (B,S,Hkv,dqk), v
+    (B,S,Hkv,dv) -> (B,S,H,dv), scores divided by ``denom`` (default
+    ``sqrt(dqk)``), queries in chunks of ``q_chunk``."""
+    b, s = q.shape[:2]
     qb = min(q_chunk, s)
     if s % qb:
         qb = math.gcd(s, qb)
@@ -134,7 +137,7 @@ def attn_forward(
         """One q-chunk: (B, qb, H, hd), (B, qb) -> (B, qb, H, hd)."""
         if ctx_parallel:
             qi = constrain(qi, "batch", "tp", None, None)
-        scores = _grouped_scores(qi, k)  # (B,Hkv,G,qb,S)
+        scores = _grouped_scores(qi, k, denom)  # (B,Hkv,G,qb,S)
         causal = k_pos[:, None, None, None, :] <= qpos[:, None, None, :, None]
         if cfg.sliding_window:
             causal &= (
@@ -161,7 +164,22 @@ def attn_forward(
     _, outs = jax.lax.scan(
         body, None, (jnp.swapaxes(qc, 0, 1), jnp.swapaxes(pc, 0, 1))
     )  # (nq, B, qb, H, hd)
-    out = jnp.swapaxes(outs, 0, 1).reshape(b, s, cfg.n_heads, cfg.head_dim_)
+    return jnp.swapaxes(outs, 0, 1).reshape(b, s, q.shape[2], v.shape[-1])
+
+
+def attn_forward(
+    cfg: ModelConfig,
+    p: dict,
+    x: jax.Array,
+    positions: jax.Array,
+    *,
+    q_chunk: int = 1024,
+    return_kv: bool = False,
+):
+    """Causal full-sequence attention (train / prefill)."""
+    b, s, _ = x.shape
+    q, k, v = _project_qkv(cfg, p, x, positions)
+    out = causal_attention(cfg, q, k, v, positions, q_chunk=q_chunk)
     y = (out.reshape(b, s, -1).astype(x.dtype)) @ p["wo"]
     if return_kv:
         return y, (k, v)
@@ -341,6 +359,7 @@ def attn_prefill_cache(
 __all__ = [
     "attn_init",
     "attn_forward",
+    "causal_attention",
     "attn_decode",
     "attn_cache_init",
     "attn_prefill_cache",

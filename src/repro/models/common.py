@@ -1,6 +1,8 @@
 """Shared model components: norms, RoPE, initializers, activations."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -36,10 +38,42 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     )
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor ``0.1 * mscale * ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_range(dim: int, theta: float, yarn) -> tuple[int, int]:
+    """The first and last rotary pair of YaRN's ramp: the pairs that turn
+    ``beta_fast`` and ``beta_slow`` times over the original context."""
+
+    def dim_for(rotations: float) -> float:
+        return (dim * math.log(yarn.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_for(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_for(yarn.beta_slow)), dim - 1)
+    return low, high
+
+
+def yarn_freqs(dim: int, theta: float, yarn) -> jax.Array:
+    """(dim/2,) YaRN inverse frequencies: the pairs below the ramp keep their
+    frequency, those above it are divided by ``factor``, linear between."""
+    extra = rope_freqs(dim, theta)
+    inter = extra / yarn.factor
+    low, high = yarn_range(dim, theta, yarn)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs: jax.Array | None = None) -> jax.Array:
+    """x: (..., S, H, hd); positions: broadcastable to (..., S); ``freqs``:
+    (hd/2,) inverse frequencies (default: plain RoPE at ``theta``)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)  # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)  # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     cos = jnp.cos(angles)[..., None, :]  # (..., S, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
@@ -54,4 +88,5 @@ def act_fn(name: str):
     ]
 
 
-__all__ = ["rms_norm", "dense_init", "embed_init", "apply_rope", "rope_freqs", "act_fn"]
+__all__ = ["rms_norm", "dense_init", "embed_init", "apply_rope", "rope_freqs",
+           "yarn_freqs", "yarn_mscale", "yarn_range", "act_fn"]

@@ -22,6 +22,24 @@ class MoEConfig:
     every: int = 1                    # MoE layer period (Jamba: 2)
     offset: int = 0                   # first MoE layer index within period
     router_dtype: str = "float32"
+    norm_topk_prob: bool = True       # renormalise the top-k weights to sum 1
+    routed_scaling_factor: float = 1.0  # routed output scale (unrenormalised)
+    seq_aux: bool = False             # DeepSeek-V2's per-sequence balance loss
+    aux_weight: float = 0.01          # the balance loss's weight in the loss
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN RoPE scaling (DeepSeek-V2's ``rope_scaling``, type ``yarn``).
+    The source's cos/sin scale ``mscale(mscale) / mscale(mscale_all_dim)``
+    is 1 where the two are equal, as in every DeepSeek-V2 and -V3 config;
+    only ``mscale_all_dim`` (the scores' scale) is kept."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -62,6 +80,12 @@ class ModelConfig:
     qk_norm: bool = False
     rope_theta: float = 10_000.0
     sliding_window: int | None = None
+    # multi-head latent attention (DeepSeek-V2); kv_lora_rank 0 => GQA
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: YarnConfig | None = None
     # ffn
     ffn_act: str = "silu"              # silu (SwiGLU) | gelu (GeGLU)
     # moe / ssm / hybrid
@@ -95,6 +119,10 @@ class ModelConfig:
         if self.head_dim is not None:
             return self.head_dim
         return self.d_model // max(self.n_heads, 1)
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def padded_vocab(self) -> int:
@@ -161,7 +189,14 @@ class ModelConfig:
         if not self.tie_embeddings:
             total += self.padded_vocab * d
         for spec in self.layer_plan():
-            if spec.kind == "attn":
+            if spec.kind == "attn" and self.mla:
+                h, r = self.n_heads, self.kv_lora_rank
+                dqk = self.qk_nope_head_dim + self.qk_rope_head_dim
+                total += d * h * dqk  # wq
+                total += d * (r + self.qk_rope_head_dim) + r  # wkv_a, kv_norm
+                total += r * h * (self.qk_nope_head_dim + self.v_head_dim)  # wkv_b
+                total += h * self.v_head_dim * d  # wo
+            elif spec.kind == "attn":
                 total += d * self.n_heads * hd  # wq
                 total += 2 * d * self.n_kv_heads * hd  # wk, wv
                 total += self.n_heads * hd * d  # wo
@@ -218,4 +253,4 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
     return replace(cfg, **base)
 
 
-__all__ = ["ModelConfig", "MoEConfig", "MambaConfig", "LayerSpec", "reduced"]
+__all__ = ["ModelConfig", "MoEConfig", "MambaConfig", "YarnConfig", "LayerSpec", "reduced"]

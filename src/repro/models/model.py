@@ -10,6 +10,10 @@ Three entry points:
   forward(cfg, params, tokens, embeds=None)        -> logits (train)
   prefill(cfg, params, tokens, max_len, ...)       -> (logits, cache)
   decode_step(cfg, params, cache, tokens, pos)     -> (logits, cache)
+
+An attention layer is GQA (``attention.py``), or multi-head latent
+attention (``mla.py``) where ``kv_lora_rank > 0``; MLA has no decode cache,
+so ``prefill``, ``decode_step`` and ``init_cache`` refuse it.
 """
 from __future__ import annotations
 
@@ -22,6 +26,7 @@ import jax.numpy as jnp
 
 from . import attention as attn
 from . import ffn as ffn_mod
+from . import mla
 from . import moe as moe_mod
 from . import ssm
 from ..runtime import telemetry
@@ -38,7 +43,9 @@ from .config import LayerSpec, ModelConfig
 def _layer_init(key: jax.Array, cfg: ModelConfig, spec: LayerSpec, dtype) -> dict:
     k1, k2, k3 = jax.random.split(key, 3)
     p: dict[str, Any] = {"ln1": jnp.zeros((cfg.d_model,), dtype)}
-    if spec.kind == "attn":
+    if spec.kind == "attn" and cfg.mla:
+        p["mixer"] = mla.mla_init(k1, cfg, dtype)
+    elif spec.kind == "attn":
         p["mixer"] = attn.attn_init(k1, cfg, dtype)
     else:
         p["mixer"] = ssm.mamba_init(k1, cfg, dtype)
@@ -90,7 +97,9 @@ def _layer_forward(cfg, spec: LayerSpec, p, x, positions):
     Each half (norm, mixer or FFN, residual add) is a ``model.*`` scope."""
     with telemetry.scope("model.attn" if spec.kind == "attn" else "model.ssm"):
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
-        if spec.kind == "attn":
+        if spec.kind == "attn" and cfg.mla:
+            mix, cache_out = mla.mla_forward(cfg, p["mixer"], h, positions), None
+        elif spec.kind == "attn":
             mix, cache_out = attn.attn_forward(cfg, p["mixer"], h, positions,
                                                return_kv=True)
         else:
@@ -215,7 +224,18 @@ def forward(
     return _head(cfg, params, x), aux_total
 
 
+def _refuse_mla_serving(cfg: ModelConfig, entry: str) -> None:
+    """Serving has no latent KV cache: an MLA configuration is refused
+    rather than given a GQA cache it does not have."""
+    if cfg.mla:
+        raise NotImplementedError(
+            f"{entry}: {cfg.name} uses multi-head latent attention "
+            f"(kv_lora_rank={cfg.kv_lora_rank}), which has no KV cache or decode "
+            "path here; only training (forward) runs it")
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict:
+    _refuse_mla_serving(cfg, "init_cache")
     dtype = jnp.dtype(cfg.dtype)
     plan = cfg.layer_plan()
 
@@ -335,6 +355,7 @@ def prefill(
     embeds: jax.Array | None = None,
 ):
     """Full-sequence pass that also builds the decode cache."""
+    _refuse_mla_serving(cfg, "prefill")
     x = _embed(cfg, params, tokens, embeds)
     b, s, _ = x.shape
     positions = jnp.arange(s)
@@ -380,6 +401,7 @@ def decode_step(
     Scalar ``pos``: all rows at the same position (one-shot serving).
     Vector ``pos`` (B,): each decode slot on its own clock — requires the
     cache in slot form (``cache_to_slots``); see ``attn.attn_decode``."""
+    _refuse_mla_serving(cfg, "decode_step")
     x = _embed(cfg, params, tokens, None)
     plan = cfg.layer_plan()
 

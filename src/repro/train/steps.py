@@ -129,8 +129,10 @@ def loss_fn(
     tokens: jax.Array,
     labels: jax.Array,
     embeds: jax.Array | None = None,
-    aux_weight: float = 0.01,
 ):
+    """Mean next-token cross-entropy plus the MoE balance loss, weighted by
+    the configuration's ``moe.aux_weight``."""
+    aux_weight = cfg.moe.aux_weight if cfg.moe is not None else 0.0
     logits, aux = M.forward(cfg, params, tokens, embeds)
     n_fe = cfg.n_frontend_tokens if embeds is not None else 0
     with telemetry.scope("model.head"):
