@@ -1,11 +1,15 @@
 """GQA/MQA/MHA attention: RoPE, qk-norm, QKV-bias, sliding-window, KV cache.
 
-Memory discipline: the full-sequence path never materializes the (S, S)
-score matrix — queries are processed in chunks of ``q_chunk`` under
-``lax.scan`` with only one (B, H, q_chunk, S) block live (flash-attention
-style blocking, single level; sufficient since S fits HBM row-wise).  GQA
-keeps K/V un-repeated via a grouped einsum, so TP sharding of q-heads never
-forces a KV all-gather.
+The causal softmax core (``causal_attention``) has two executors.  On the
+TPU, where positions are ``0..S-1`` (the caller says so, statically), no
+sliding window applies, no multi-device mesh is installed and the sequence
+tiles, it is the Pallas flash kernel (``kernels/flash_attention.py``): each
+score tile stays in VMEM and blocks above the diagonal are skipped.
+Everywhere else (the CPU, serving's cache positions, gemma-style windows,
+context parallelism) it is the chunked XLA path: queries in chunks of
+``q_chunk`` under ``lax.scan``, one f32 (B, H, q_chunk, S) block live at a
+time and recomputed in the backward.  GQA keeps K/V un-repeated via a
+grouped einsum, so TP sharding of q-heads never forces a KV all-gather.
 """
 from __future__ import annotations
 
@@ -15,7 +19,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..runtime.sharding import constrain, tp_size
+from ..kernels import flash_attention
+from ..runtime import telemetry
+from ..runtime.sharding import ambient_mesh, constrain, tp_size
 from .common import apply_rope, dense_init, rms_norm
 from .config import ModelConfig
 
@@ -100,6 +106,25 @@ def _grouped_out(probs: jax.Array, v: jax.Array) -> jax.Array:
     return out.reshape(b, sq, hkv * g, hd)
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _flash_block(cfg: ModelConfig, q: jax.Array, k: jax.Array,
+                 arange_positions: bool) -> int | None:
+    """The flash kernel's block where the core may run as the kernel, else
+    None: on the TPU, for positions ``0..S-1``, no sliding window, no mesh
+    of more than one device (a Pallas call is one device's program; the
+    context-parallel path lives only on such a mesh), whole q-head groups
+    and a sequence the kernel tiles (``flash_attention.block_for``)."""
+    if not arange_positions or cfg.sliding_window or not _on_tpu():
+        return None
+    mesh = ambient_mesh()
+    if (mesh is not None and mesh.size > 1) or q.shape[2] % k.shape[2]:
+        return None
+    return flash_attention.block_for(q.shape[1])
+
+
 def causal_attention(
     cfg: ModelConfig,
     q: jax.Array,
@@ -109,10 +134,26 @@ def causal_attention(
     *,
     q_chunk: int = 1024,
     denom: float | None = None,
+    arange_positions: bool = False,
 ) -> jax.Array:
     """The causal softmax core: q (B,S,H,dqk), k (B,S,Hkv,dqk), v
     (B,S,Hkv,dv) -> (B,S,H,dv), scores divided by ``denom`` (default
-    ``sqrt(dqk)``), queries in chunks of ``q_chunk``."""
+    ``sqrt(dqk)``).  ``arange_positions`` states that ``positions`` are
+    ``0..S-1``, which the flash kernel assumes (``_flash_block``); the
+    chunked XLA path (queries in chunks of ``q_chunk``) runs otherwise.
+    Each trace counts ``attn.core.kernel`` or ``attn.core.xla``."""
+    with telemetry.scope("model.attn.core"):
+        block = _flash_block(cfg, q, k, arange_positions)
+        if block is not None:
+            telemetry.counter_inc("attn.core.kernel")
+            scale = 1.0 / (math.sqrt(q.shape[-1]) if denom is None else denom)
+            return flash_attention.causal_flash_attention(q, k, v, scale=scale, block=block)
+        telemetry.counter_inc("attn.core.xla")
+        return _chunked_attention(cfg, q, k, v, positions, q_chunk=q_chunk, denom=denom)
+
+
+def _chunked_attention(cfg, q, k, v, positions, *, q_chunk, denom):
+    """The XLA executor of ``causal_attention``."""
     b, s = q.shape[:2]
     qb = min(q_chunk, s)
     if s % qb:
@@ -175,11 +216,14 @@ def attn_forward(
     *,
     q_chunk: int = 1024,
     return_kv: bool = False,
+    arange_positions: bool = False,
 ):
-    """Causal full-sequence attention (train / prefill)."""
+    """Causal full-sequence attention (train / prefill); ``arange_positions``
+    as ``causal_attention``'s."""
     b, s, _ = x.shape
     q, k, v = _project_qkv(cfg, p, x, positions)
-    out = causal_attention(cfg, q, k, v, positions, q_chunk=q_chunk)
+    out = causal_attention(cfg, q, k, v, positions, q_chunk=q_chunk,
+                           arange_positions=arange_positions)
     y = (out.reshape(b, s, -1).astype(x.dtype)) @ p["wo"]
     if return_kv:
         return y, (k, v)

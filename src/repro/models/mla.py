@@ -61,8 +61,9 @@ def mla_init(key: jax.Array, cfg: ModelConfig, dtype) -> dict:
 
 
 def mla_forward(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
-                *, q_chunk: int = 1024) -> jax.Array:
-    """Causal full-sequence latent attention: x (B, S, D) -> (B, S, D)."""
+                *, q_chunk: int = 1024, arange_positions: bool = False) -> jax.Array:
+    """Causal full-sequence latent attention: x (B, S, D) -> (B, S, D);
+    ``arange_positions`` as ``attention.causal_attention``'s."""
     b, s, _ = x.shape
     h, r = cfg.n_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -78,7 +79,8 @@ def mla_forward(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe, (b, s, h, dr))], axis=-1)
     out = causal_attention(cfg, q, k, v, positions, q_chunk=q_chunk,
-                           denom=1.0 / softmax_scale(cfg))
+                           denom=1.0 / softmax_scale(cfg),
+                           arange_positions=arange_positions)
     return out.reshape(b, s, h * dv).astype(x.dtype) @ p["wo"]
 
 

@@ -93,15 +93,18 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
 
 
 def _layer_forward(cfg, spec: LayerSpec, p, x, positions):
-    """Full-sequence layer.  Returns (x, aux, kv_for_cache|state|None).
-    Each half (norm, mixer or FFN, residual add) is a ``model.*`` scope."""
+    """Full-sequence layer over ``positions``, which are ``0..S-1`` (the
+    attention core may assume it).  Returns (x, aux,
+    kv_for_cache|state|None).  Each half (norm, mixer or FFN, residual add)
+    is a ``model.*`` scope."""
     with telemetry.scope("model.attn" if spec.kind == "attn" else "model.ssm"):
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
         if spec.kind == "attn" and cfg.mla:
-            mix, cache_out = mla.mla_forward(cfg, p["mixer"], h, positions), None
+            mix = mla.mla_forward(cfg, p["mixer"], h, positions, arange_positions=True)
+            cache_out = None
         elif spec.kind == "attn":
             mix, cache_out = attn.attn_forward(cfg, p["mixer"], h, positions,
-                                               return_kv=True)
+                                               return_kv=True, arange_positions=True)
         else:
             mix, cache_out = ssm.mamba_forward(cfg, p["mixer"], h, return_state=True)
         x = x + mix

@@ -212,3 +212,38 @@ def test_kron_stages_pass_outputs_as_bitcasts(one_chip, monkeypatch, m, dtype, v
             if opcode in ("reshape", "copy", "transpose") and count == size
         ]
         assert not relayouts, (name, relayouts)
+
+
+# ---------------------------------------------------------------------------
+# The causal attention core's flash kernel at the training cells' shapes
+# ---------------------------------------------------------------------------
+
+# (id, B, S, H, Hkv, Dqk, Dv): qwen3-4b-train (2 x 2048, 32/8 heads of 128)
+# and dsv2lite-train (1 x 4096, 16 latent heads of 192/128), bf16.
+ATTENTION = [
+    ("qwen3_4b", 2, 2048, 32, 8, 128, 128),
+    ("dsv2lite", 1, 4096, 16, 16, 192, 128),
+]
+
+
+@pytest.mark.parametrize("b,s,h,hkv,dqk,dv", [c[1:] for c in ATTENTION],
+                         ids=[c[0] for c in ATTENTION])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_compiles_for_v5e(one_chip, direction, b, s, h, hkv, dqk, dv):
+    """The forward kernel, and the forward with both backward kernels (a
+    custom call each), compile at full size for the chip."""
+    from repro.kernels.flash_attention import causal_flash_attention
+
+    shape = lambda *d: jax.ShapeDtypeStruct(d, jnp.bfloat16, sharding=one_chip)  # noqa: E731
+    q, k, v, ct = shape(b, s, h, dqk), shape(b, s, hkv, dqk), shape(b, s, hkv, dv), shape(b, s, h, dv)
+
+    def fwd(q, k, v):
+        return causal_flash_attention(q, k, v, scale=dqk ** -0.5)
+
+    def bwd(q, k, v, ct):
+        return jax.vjp(fwd, q, k, v)[1](ct)
+
+    fn, args = (fwd, (q, k, v)) if direction == "fwd" else (bwd, (q, k, v, ct))
+    with jax.enable_x64(False):
+        hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert hlo.count("tpu_custom_call") == (1 if direction == "fwd" else 3)
